@@ -2,8 +2,10 @@
 
 The main bench process keeps the single real CPU device (per the dry-run
 isolation rule); collective benchmarks run named cases from
-benchmarks/mp_bench.py in a subprocess with N host devices and emit
-``ROW,<name>,<us>,<derived>`` lines that the parent collects.
+benchmarks/mp_bench.py in a subprocess with N virtual CPU devices and emit
+``ROW,<name>,<us>,<derived>`` lines that the parent collects. Those rows
+are CPU measurements whatever device the parent holds, and each one says
+so in its ``derived`` field.
 """
 
 from __future__ import annotations
@@ -51,7 +53,8 @@ def run_mp_case(case: str, ndev: int = 8, timeout: int = 900,
     for line in proc.stdout.splitlines():
         if line.startswith("ROW,"):
             _, name, us, derived = line.split(",", 3)
-            rows.append((name, float(us), derived))
+            rows.append((name, float(us),
+                         f"{derived} [device=cpu x{ndev} virtual]"))
     return rows
 
 

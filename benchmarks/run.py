@@ -33,6 +33,10 @@ def main() -> None:
                          "BENCH_collectives.json)")
     args = ap.parse_args()
 
+    import jax
+
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bench_barrier, bench_collectives, bench_fabric,
                             bench_p2p, bench_reduce, bench_serve, bench_spmv)
     modules = {
@@ -77,6 +81,12 @@ def main() -> None:
             "fast": args.fast,
             "platform": {"python": platform.python_version(),
                          "machine": platform.machine()},
+            # the device this process's rows ran on; rows measured in a
+            # subprocess on virtual CPU devices say so in their own
+            # ``derived`` field (benchmarks.common.run_mp_case)
+            "device": {"platform": jax.devices()[0].platform,
+                       "kind": jax.devices()[0].device_kind,
+                       "count": len(jax.devices())},
             "sections": sections,
             "failures": failures,
         }

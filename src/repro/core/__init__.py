@@ -8,7 +8,7 @@
 - collectives.py: executable shard_map collectives (explicit + fused + 2-level)
 - p2p.py:         rank-addressed messaging w/ eager|1-copy protocol selection
 - protocol.py:    the Fig.3 latency/bandwidth protocol model
-- compat.py:      shard_map/make_mesh facade across jax versions
+- compat.py:      shard_map/make_mesh facade (all-auto mesh axes)
 """
 
 from repro.core.comm import (AxisComm, Comm, CommError, CommStream,  # noqa: F401

@@ -237,8 +237,10 @@ class CommStream:
         # record a COPY of a 1-element slice: the caller may donate `value`
         # into its next step (the serving engine does), which must not
         # delete the stream tail — and the tail must not pin a full buffer
-        # (the prefill stream's first leaf is a whole KV page)
-        self._token = jnp.copy(leaves[0].ravel()[:1])
+        # (the prefill stream's first leaf is a whole KV page). The tail
+        # is always float32, so each gate above compiles once per value
+        # structure, whatever kind of value the stream ordered last.
+        self._token = jnp.copy(leaves[0].ravel()[:1].astype(jnp.float32))
         return value
 
 

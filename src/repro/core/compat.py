@@ -1,11 +1,8 @@
-"""JAX version compatibility for the communication substrate.
+"""``shard_map`` and ``make_mesh`` as every internal call site uses them.
 
-The repo targets the modern ``jax.shard_map`` API (keyword ``mesh``,
-``check_vma``, partial-manual via ``axis_names``). Older installs (< 0.5)
-only ship ``jax.experimental.shard_map.shard_map`` with ``check_rep`` and
-the complementary ``auto`` frozenset, and ``jax.make_mesh`` without
-``axis_types``. Every internal call site goes through these wrappers so the
-rest of the codebase is version-agnostic.
+The repo targets ``jax.shard_map`` (keyword ``mesh``, ``check_vma``,
+partial-manual regions via ``axis_names``) and meshes whose axes are all
+``AxisType.Auto``.
 """
 
 from __future__ import annotations
@@ -14,48 +11,23 @@ from typing import Optional, Sequence
 
 import jax
 
-_HAS_NATIVE = hasattr(jax, "shard_map")
-if not _HAS_NATIVE:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-# Old XLA miscompiles all-gather/ppermute/axis-index inside PARTIAL-manual
-# regions (manual-subgroup sharding check failures in the SPMD partitioner).
-# Callers that can degrade to a fully-manual region (redundant compute on
-# the auto axes) should consult this flag.
-HAS_PARTIAL_MANUAL = _HAS_NATIVE
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
               check_vma: Optional[bool] = None):
     """``jax.shard_map`` facade.
 
-    axis_names: axes MANUAL inside ``f`` (None = all mesh axes). On old jax
-    this lowers to the ``auto=`` complement of the experimental API.
-    """
-    if _HAS_NATIVE:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
+    axis_names: axes MANUAL inside ``f`` (None = all mesh axes)."""
     kw = {}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs,
-                          check_rep=bool(check_vma) if check_vma is not None
-                          else False, **kw)
+        kw["axis_names"] = set(axis_names)
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with all axes auto, on any supported jax."""
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(names),
-                axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(names)))
-        except TypeError:
-            pass
-    return jax.make_mesh(tuple(shape), tuple(names))
+    """``jax.make_mesh`` with all axes auto."""
+    names = tuple(names)
+    return jax.make_mesh(tuple(shape), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names))

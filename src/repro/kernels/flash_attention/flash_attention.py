@@ -82,7 +82,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                      "interpret"))
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         q_offset: int = 0, block_q: int = 128,
-                        block_k: int = 128, interpret: bool = True):
+                        block_k: int = 128, interpret: bool = False):
     """q: (B, H, Sq, hd); k, v: (B, Hkv, Sk, hd) with H % Hkv == 0.
     Returns (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape

@@ -1,8 +1,8 @@
 """jit'd public wrapper for flash attention (layout adapter + dispatch).
 
-Models hold (B, S, H, hd); the kernel wants (B, H, S, hd). On TPU set
-interpret=False; interpret=True executes the kernel body in python on CPU
-for validation (this container).
+Models hold (B, S, H, hd); the kernel wants (B, H, S, hd).
+``interpret=True`` executes the kernel body in Python on the CPU (the
+tests); the default compiles it for the TPU.
 """
 
 from __future__ import annotations
@@ -19,7 +19,7 @@ from repro.kernels.flash_attention.flash_attention import flash_attention_fwd
     static_argnames=("causal", "window", "q_offset", "block_q", "block_k",
                      "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
-                    block_q=128, block_k=128, interpret=True):
+                    block_q=128, block_k=128, interpret=False):
     """q: (B, Sq, H, hd); k, v: (B, Sk, Hkv, hd) -> (B, Sq, H, hd)."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

@@ -38,7 +38,7 @@ def _one_copy_kernel(src_ref, dst_ref):
 
 @functools.partial(jax.jit, static_argnames=("cell_elems", "interpret"))
 def eager_copy(msg: jax.Array, *, cell_elems: int = 2048,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool = False) -> jax.Array:
     """Eager-protocol copy: message staged through one reused VMEM cell
     (the bounded cell pool). msg: 1-D, length multiple of cell_elems
     (ops.py pads)."""
@@ -58,7 +58,7 @@ def eager_copy(msg: jax.Array, *, cell_elems: int = 2048,
 
 @functools.partial(jax.jit, static_argnames=("block_elems", "interpret"))
 def one_copy(msg: jax.Array, *, block_elems: int = 65536,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool = False) -> jax.Array:
     """1-copy protocol: direct blocked DMA, no staging."""
     (n,) = msg.shape
     block = min(block_elems, n)

@@ -21,7 +21,7 @@ def _pad_to(x, m):
 
 
 def msgq_copy(msg, *, force_protocol: Optional[str] = None,
-              cell_elems: int = 1024, interpret: bool = True):
+              cell_elems: int = 1024, interpret: bool = False):
     """Copy a message through the selected protocol. msg: any shape."""
     flat = msg.reshape(-1)
     nbytes = flat.size * flat.dtype.itemsize

@@ -1,38 +1,23 @@
-"""jit'd public wrapper for paged attention (layout adapter + dispatch).
+"""Public entry point for paged attention.
 
 The serving engine holds decode queries as (B, 1, H, hd) rows and the
-block pool as (P, bs, Gs, hd); the kernel wants the squeezed (B, H, hd)
-query. A (B, K, H, hd) query with K > 1 is a speculative-verify q-block
-(query j at absolute position ``lengths[b] - K + j``) and dispatches the
-multi-query kernel, returning (B, K, H, hd). On TPU set interpret=False;
-interpret=True executes the kernel body in python on CPU for validation
-(this container).
+block pool as (P, bs, Gs, hd). A (B, K, H, hd) query with K > 1 is a
+speculative-verify q-block (query j at absolute position
+``lengths[b] - K + j``); (B, H, hd) is a single query per row. Every form
+runs the same q-block kernel. ``interpret=True`` executes the kernel body
+in Python on the CPU (the tests); the default compiles it for the TPU.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-
 from repro.kernels.paged_attention.paged_attention import paged_attention_fwd
 
 
-@functools.partial(
-    jax.jit, static_argnames=("window", "softcap", "interpret"))
 def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                     window: int = 0, softcap: float = 0.0,
-                    interpret: bool = True):
-    """q: (B, H, hd), (B, 1, H, hd), or (B, K, H, hd) with K > 1 (q-block
-    verify); k_pages, v_pages: (P, bs, Hkv, hd); block_tables: (B, NB)
-    int32; lengths: (B,) int32 -> same rank as q."""
-    squeezed = q.ndim == 4 and q.shape[1] == 1
-    if squeezed:
-        q = q[:, 0]
-    out = paged_attention_fwd(q, k_pages, v_pages,
-                              jnp.asarray(block_tables, jnp.int32),
-                              jnp.asarray(lengths, jnp.int32),
-                              window=window, softcap=softcap,
-                              interpret=interpret)
-    return out[:, None] if squeezed else out
+                    interpret: bool = False):
+    """q: (B, H, hd) or (B, K, H, hd); k_pages, v_pages: (P, bs, Hkv, hd);
+    block_tables: (B, NB) int32; lengths: (B,) int32 -> same rank as q."""
+    return paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths,
+                               window=window, softcap=softcap,
+                               interpret=interpret)

@@ -12,7 +12,7 @@ from repro.kernels.ssd_scan.ssd_scan import ssd_scan_fwd
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "interpret", "return_state"))
 def ssd_scan(x, dt, A, Bm, Cm, initial_state=None, *, chunk: int = 128,
-             interpret: bool = True, return_state: bool = False):
+             interpret: bool = False, return_state: bool = False):
     """See ssd_scan_fwd. Oracle: ref.ssd_scan_ref (sequential recurrence).
 
     ``initial_state``/``return_state`` thread the carried scan state

@@ -23,8 +23,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, y_ref, fs_ref,
-                state_scr, *, chunk: int):
+def _ssd_kernel(x_ref, dtr_ref, dtc_ref, a_ref, b_ref, c_ref, s0_ref, y_ref,
+                fs_ref, state_scr, *, chunk: int):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
@@ -35,20 +35,28 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, y_ref, fs_ref,
         state_scr[...] = s0_ref[0, 0].astype(jnp.float32)
 
     x = x_ref[0, 0].astype(jnp.float32)        # (l, p)
-    dt = dt_ref[0, 0].astype(jnp.float32)      # (l,)
-    A = a_ref[0]                               # scalar (negative)
+    A = a_ref[pl.program_id(1)]                # scalar (negative), SMEM
+    dA_row = dtr_ref[0, 0].astype(jnp.float32) * A   # (1, l)
+    dt_col = dtc_ref[0, 0].astype(jnp.float32)       # (l, 1)
+    dA_col = dt_col * A                              # (l, 1)
     Bm = b_ref[0, 0].astype(jnp.float32)       # (l, n)
     Cm = c_ref[0, 0].astype(jnp.float32)       # (l, n)
 
-    dA = dt * A                                # (l,)
-    cum = jnp.cumsum(dA)                       # (l,)
-    # lower-triangular decay matrix L[i,j] = exp(sum_{k=j+1..i} dA_k)
-    seg = cum[:, None] - cum[None, :]
+    # inclusive prefix sums of dA as a column and as a row: masked
+    # reductions of the broadcast vector (Mosaic has no cumsum, and dt
+    # arrives in both layouts so neither needs a transpose)
     li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    Lm = jnp.where(li >= lj, jnp.exp(seg), 0.0)
+    lower = li >= lj
+    cum_col = jnp.sum(jnp.where(lower, dA_row, 0.0), axis=1,
+                      keepdims=True)                 # (l, 1)
+    cum_row = jnp.sum(jnp.where(lower, 0.0, dA_col), axis=0,
+                      keepdims=True) + dA_row        # (1, l)
+    total = jnp.sum(dA_row, axis=1, keepdims=True)   # (1, 1)
+    # lower-triangular decay matrix L[i,j] = exp(sum_{k=j+1..i} dA_k)
+    Lm = jnp.where(lower, jnp.exp(cum_col - cum_row), 0.0)
 
-    xdt = x * dt[:, None]                      # (l, p)
+    xdt = x * dt_col                           # (l, p)
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * Lm          # (l, l)
@@ -60,14 +68,14 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, y_ref, fs_ref,
     # contribution of the carried state: exp(cum) * C @ state^T
     y_off = jax.lax.dot_general(
         Cm, state, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * jnp.exp(cum)[:, None]
+        preferred_element_type=jnp.float32) * jnp.exp(cum_col)
 
     # fold this chunk into the state
-    decay_states = jnp.exp(cum[-1] - cum)      # (l,)
+    decay_states = jnp.exp(total - cum_col)    # (l, 1)
     upd = jax.lax.dot_general(
-        xdt, Bm * decay_states[:, None], (((0,), (0,)), ((), ())),
+        xdt, Bm * decay_states, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)               # (p, n)
-    new_state = state * jnp.exp(cum[-1]) + upd
+    new_state = state * jnp.exp(total) + upd
     state_scr[...] = new_state
     # every chunk writes the running state to the same output block —
     # the last (sequentially final) chunk's write is what survives
@@ -79,7 +87,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, y_ref, fs_ref,
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "interpret", "return_state"))
 def ssd_scan_fwd(x, dt, A, Bm, Cm, initial_state=None, *, chunk: int = 128,
-                 interpret: bool = True, return_state: bool = False):
+                 interpret: bool = False, return_state: bool = False):
     """x: (B,H,S,p); dt: (B,H,S) (post-softplus); A: (H,) negative;
     Bm, Cm: (B,S,n) (ngroups=1). Returns y (B,H,S,p).
 
@@ -100,8 +108,9 @@ def ssd_scan_fwd(x, dt, A, Bm, Cm, initial_state=None, *, chunk: int = 128,
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, l, p), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, 1, l), lambda b, h, ic: (b, h, ic)),
-            pl.BlockSpec((1,), lambda b, h, ic: (h,)),
+            pl.BlockSpec((1, 1, 1, l), lambda b, h, ic: (b, h, 0, ic)),
+            pl.BlockSpec((1, 1, l, 1), lambda b, h, ic: (b, h, ic, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, l, n), lambda b, h, ic: (b, 0, ic, 0)),
             pl.BlockSpec((1, 1, l, n), lambda b, h, ic: (b, 0, ic, 0)),
             pl.BlockSpec((1, 1, p, n), lambda b, h, ic: (b, h, 0, 0)),
@@ -116,7 +125,8 @@ def ssd_scan_fwd(x, dt, A, Bm, Cm, initial_state=None, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bm.reshape(B, 1, S, n), Cm.reshape(B, 1, S, n),
+    )(x, dt.reshape(B, H, 1, S), dt.reshape(B, H, S, 1), A,
+      Bm.reshape(B, 1, S, n), Cm.reshape(B, 1, S, n),
       initial_state.astype(jnp.float32))
     if return_state:
         return y, final_state

@@ -219,8 +219,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
     if verbose:
         print(compiled.memory_analysis())   # proves it fits
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # old jax: per-device dicts
-            cost = cost[0] if cost else {}
         print({k: v for k, v in cost.items()
                if k in ("flops", "bytes accessed")})
 

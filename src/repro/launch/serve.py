@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.config import ServeConfig, TrainConfig
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
+from repro.launch.cache import use_compile_cache
 from repro.models.registry import build_model, make_synthetic_batch
 from repro.obs import metrics as obs_metrics
 from repro.obs import residuals as obs_residuals
@@ -62,11 +63,22 @@ FAMILY_ARCHS = ("gemma-2b", "olmoe-1b-7b", "mamba2-370m", "hymba-1.5b",
                 "whisper-tiny")
 
 
+def serving_model(arch: str, *, smoke: bool, ring: bool = False):
+    """``(cfg, dtype, model)`` as every serving run builds them: the smoke
+    config in float32, or the published config in bfloat16."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    dtype = "float32" if smoke else "bfloat16"
+    tcfg = TrainConfig(param_dtype=dtype, compute_dtype=dtype, remat=False,
+                       loss_chunk=64, attn_chunk_threshold=4096)
+    return cfg, dtype, build_model(cfg, tcfg, ServeConfig(ring_buffer=ring),
+                                   tp=1)
+
+
 def effective_chunk(caps, prefill_chunk: int) -> int:
     """Capability-aware chunk size: floor to the family's
-    ``chunk_multiple`` (SSM/hybrid scans resume bit-exactly only on
-    ``ssm_chunk`` boundaries), never below one multiple; 0 (monolithic)
-    when the family cannot chunk at all."""
+    ``chunk_multiple`` (SSM/hybrid scans resume on the monolithic decay
+    grid only at ``ssm_chunk`` boundaries), never below one multiple;
+    0 (monolithic) when the family cannot chunk at all."""
     if prefill_chunk <= 0 or not caps.chunked_prefill:
         return 0
     m = max(1, int(caps.chunk_multiple))
@@ -254,14 +266,17 @@ def drive_fabric(fab: ServingFabric, requests: List[ServeRequest]
     return stats
 
 
-def _warm_fabric(fab: ServingFabric, cfg, *, dtype: str, seed: int,
-                 prompt_len: int) -> None:
+def warm_fabric(fab: ServingFabric, cfg, *, dtype: str, seed: int,
+                prompt_len: int) -> None:
     """Compile every rank's jits off the clock (chunk + decode dispatch,
     and on the disaggregated path the migrate copy + state import), then
     reset the whole fabric — warm requests must leave no queue entries,
     leases, device state or accounting behind (PR-5 satellite: the
-    scheduler's rid-keyed maps are exactly what this reset must clear)."""
-    trace = make_trace(2 * fab.ranks, prompt_len=prompt_len, max_new=2,
+    scheduler's rid-keyed maps are exactly what this reset must clear).
+    Four new tokens give every rank at least two decode dispatches, so
+    its stream gates (which start at the second dispatch) compile here
+    too."""
+    trace = make_trace(2 * fab.ranks, prompt_len=prompt_len, max_new=4,
                        arrival="all", seed=seed + 7)
     for req in requests_from_trace(cfg, trace, dtype=dtype, seed=seed + 7):
         fab.submit(req, 0.0)
@@ -292,11 +307,7 @@ def run_fabric(arch: str = "gemma-2b", *, smoke: bool = True,
     the single-engine baseline (every fabric rank runs the same chunked
     paged engine, so placement must not change a single sampled token).
     """
-    cfg = get_smoke_config(arch) if smoke else get_config(arch)
-    dtype = "float32" if smoke else "bfloat16"
-    tcfg = TrainConfig(param_dtype=dtype, compute_dtype=dtype, remat=False,
-                       loss_chunk=64, attn_chunk_threshold=4096)
-    model = build_model(cfg, tcfg, ServeConfig(), tp=1)
+    cfg, dtype, model = serving_model(arch, smoke=smoke)
     if model.decode_step_paged is None:
         raise ValueError(f"arch {cfg.name!r} has no paged decode path; "
                          "the serving fabric runs paged engines only")
@@ -355,8 +366,8 @@ def run_fabric(arch: str = "gemma-2b", *, smoke: bool = True,
                             n_prefill_ranks=n_prefill_ranks,
                             speculate=spec_k)
         try:
-            _warm_fabric(fab, cfg, dtype=dtype, seed=seed,
-                         prompt_len=plens[0])
+            warm_fabric(fab, cfg, dtype=dtype, seed=seed,
+                        prompt_len=plens[0])
             reqs = requests_from_trace(cfg, trace, dtype=dtype, seed=seed)
             result[f"fabric_{placement}"] = drive_fabric(fab, reqs)
             ident = bool(all(
@@ -390,12 +401,7 @@ def run_family_rows(archs=FAMILY_ARCHS, *, smoke: bool = True,
     reports its capability reason instead of faking a number."""
     rows: List[Dict] = []
     for arch in archs:
-        cfg = get_smoke_config(arch) if smoke else get_config(arch)
-        dtype = "float32" if smoke else "bfloat16"
-        tcfg = TrainConfig(param_dtype=dtype, compute_dtype=dtype,
-                           remat=False, loss_chunk=64,
-                           attn_chunk_threshold=4096)
-        model = build_model(cfg, tcfg, ServeConfig(), tp=1)
+        cfg, dtype, model = serving_model(arch, smoke=smoke)
         caps = model.capabilities
         row: Dict = {"family": cfg.name, "block": cfg.block,
                      "chunked_prefill": bool(caps.chunked_prefill),
@@ -494,12 +500,7 @@ def run_traffic(arch: str = "gemma-2b", *, smoke: bool = True,
     acceptance, and trace-level token identity — speculation must not
     change one greedy token (schema v7).
     """
-    cfg = get_smoke_config(arch) if smoke else get_config(arch)
-    dtype = "float32" if smoke else "bfloat16"
-    tcfg = TrainConfig(param_dtype=dtype, compute_dtype=dtype, remat=False,
-                       loss_chunk=64, attn_chunk_threshold=4096)
-    scfg = ServeConfig(ring_buffer=ring)
-    model = build_model(cfg, tcfg, scfg, tp=1)
+    cfg, dtype, model = serving_model(arch, smoke=smoke, ring=ring)
     # capability-aware chunk selection (DESIGN.md §13): floor the chunk
     # to the family's multiple; patch_stub frontends run monolithic; an
     # enc-dec family chunks on the paged path only, so its slot runs
@@ -619,9 +620,8 @@ def run_traffic(arch: str = "gemma-2b", *, smoke: bool = True,
             # capability.
             draft_model = draft_params = None
             if draft_arch not in ("self", arch):
-                dcfg = (get_smoke_config(draft_arch) if smoke
-                        else get_config(draft_arch))
-                draft_model = build_model(dcfg, tcfg, scfg, tp=1)
+                _, _, draft_model = serving_model(draft_arch, smoke=smoke,
+                                                  ring=ring)
                 draft_params = draft_model.init(jax.random.PRNGKey(seed))
             nblocks = max(1, (slots * cache_len) // block_size)
             rows = min(requests, nblocks)
@@ -792,7 +792,10 @@ def _finalize_payload(payload: Dict) -> Dict:
     """Schema v8: merge every sub-run's residual report into one
     payload-level ``residual_report`` with flat ``residual_<hop>_ratio``
     keys and the summed ``serialization_stall_s`` (all absent when
-    telemetry was off)."""
+    telemetry was off). Every payload names the device it ran on."""
+    dev = jax.devices()[0]
+    payload["device"] = {"platform": dev.platform, "kind": dev.device_kind,
+                         "count": len(jax.devices())}
     reps = _collect_reports(payload)
     if reps:
         merged = obs_residuals.merge_reports(reps)
@@ -818,6 +821,7 @@ def _write_trace(path) -> None:
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b", choices=list(ARCH_NAMES))
     ap.add_argument("--config", default=None, metavar="NAME[,NAME...]",

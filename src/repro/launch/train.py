@@ -21,6 +21,7 @@ from repro.config import MeshConfig, ServeConfig, TrainConfig
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.data import SyntheticPipeline
 from repro.dist.sharding import batch_pspec
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_mesh_from_config
 from repro.models.registry import build_model
 from repro.train import checkpoint as ckpt
@@ -28,6 +29,7 @@ from repro.train.trainer import init_train_state, make_train_step
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCH_NAMES))
     ap.add_argument("--smoke", action="store_true")

@@ -158,8 +158,11 @@ def ssm_apply(p, x, cfg, initial_state=None, return_state=False):
 
 
 def ssm_apply_chunk(p, x, cfg, state, n_valid):
-    """Chunk-resumed SSD block: one engine prefill chunk, bit-exact with
-    the matching slice of :func:`ssm_apply` over the whole prompt.
+    """Chunk-resumed SSD block: one engine prefill chunk, equal to the
+    matching slice of :func:`ssm_apply` over the whole prompt up to
+    matmul rounding (a backend may accumulate the ``in_proj`` matmul of a
+    C-row chunk in another order than that of the whole prompt, so the
+    raw conv rows can differ by a few ulps).
 
     x (B, C, d) — the chunk's hidden states (tail rows may be padding);
     state — the carried-state pytree {conv (B, k-1, conv_dim) raw
@@ -168,8 +171,8 @@ def ssm_apply_chunk(p, x, cfg, state, n_valid):
     monolithic left zero-pad / zero initial state); n_valid (B,) — valid
     rows in this chunk. Returns (out (B, C, d), new state).
 
-    Exactness requires the caller to split prompts at multiples of
-    ``cfg.ssm_chunk`` (the engine's ``chunk_multiple`` capability): the
+    The scan composes exactly only when the caller splits prompts at
+    multiples of ``cfg.ssm_chunk`` (the engine's ``chunk_multiple``): the
     inner ssd chunk grid then lands on the same global boundaries as the
     monolithic scan, so every decay product is the same float sequence.
     """

@@ -140,8 +140,6 @@ def analyze_compiled(compiled, *, hw: HW = V5E, model_flops: float = None,
     ``computed_flops_per_device`` and ``bytes_per_device`` from
     roofline.flops (preferred source for compute/memory terms)."""
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # old jax: one dict per device
-        cost = cost[0] if cost else {}
     raw_flops = float(cost.get("flops", 0.0))
     raw_bytes = float(cost.get("bytes accessed", 0.0))
     hlo = hlo_text if hlo_text is not None else compiled.as_text()

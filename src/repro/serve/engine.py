@@ -208,6 +208,13 @@ class ContinuousEngine:
     ``step(now)`` is one micro-step; drive it from a traffic loop (see
     ``repro.launch.serve``) or use :meth:`generate` for the batch-API
     convenience path (same-arrival batch, used by the parity tests).
+
+    ``device`` pins the engine to one device: params, pools and decode
+    state are committed there, and stay committed across :meth:`reset`,
+    so every dispatch sees the same placement and compiles once (a mix
+    of fresh uncommitted buffers and committed dispatch outputs would
+    compile each program again per mix). ``None`` leaves placement to
+    JAX's default device.
     """
 
     def __init__(self, model, params, *, cache_len: int, num_slots: int,
@@ -216,7 +223,8 @@ class ContinuousEngine:
                  prefill_chunk: int = 64, kv_layout: str = "slot",
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  role: str = "full", prefix_cache: bool = False,
-                 speculate: int = 0, draft_model=None, draft_params=None):
+                 speculate: int = 0, draft_model=None, draft_params=None,
+                 device=None):
         if kv_layout not in ("slot", "paged"):
             raise ValueError(f"unknown kv_layout {kv_layout!r} "
                              "(expected 'slot' or 'paged')")
@@ -234,7 +242,8 @@ class ContinuousEngine:
         #: prefilling them. ``"full"`` is the single-engine behavior.
         self.role = role
         self.model = model
-        self.params = params
+        self.device = device
+        self.params = self._on_device(params)
         self.cache_len = cache_len
         self.eos_id = eos_id
         self.max_prefill_per_step = max(1, int(max_prefill_per_step))
@@ -302,6 +311,7 @@ class ContinuousEngine:
                                    max_blocks_per_req=mbr)
         else:
             self.kv = SlotKVCache(model, cache_len, num_slots)
+        self.kv.swap_buffers(self._on_device(self.kv.buffers))
         if prefix_cache:
             # radix-tree prefix cache (DESIGN.md §12): admission walks
             # the trie, leases every hit block at refcount+1, and starts
@@ -381,7 +391,7 @@ class ContinuousEngine:
                         "the drafter resyncs through its own teacher-"
                         "forced verify dispatch (verify_step_paged)")
             self.draft_model = draft_model
-            self.draft_params = draft_params
+            self.draft_params = self._on_device(draft_params)
             # the drafter's pool mirrors the target's geometry so rows
             # and leases stay 1:1 (alloc/free in lockstep); its HBM cost
             # is the drafter's own (smaller) per-token KV
@@ -389,6 +399,7 @@ class ContinuousEngine:
                 draft_model, num_blocks=self.kv.pool.num_blocks,
                 block_size=self.kv.block_size, num_slots=num_slots,
                 max_blocks_per_req=self.kv.max_blocks_per_req)
+            self.draft_kv.swap_buffers(self._on_device(self.draft_kv.buffers))
             #: drafter's canonical resident tokens per row (host-side;
             #: the drafter pool's own length bookkeeping is unused — the
             #: model path takes explicit positions)
@@ -522,14 +533,18 @@ class ContinuousEngine:
                     if isinstance(buf, dict) and name in buf)
         return total // max(1, self.kv.num_slots)
 
-    @staticmethod
-    def _fresh_state(S: int):
-        return {
+    def _on_device(self, tree):
+        """``tree`` committed to the engine's device (as is, without one)."""
+        return tree if self.device is None else jax.device_put(tree,
+                                                               self.device)
+
+    def _fresh_state(self, S: int):
+        return self._on_device({
             "tok": jnp.zeros((S, 1, 1), jnp.int32),    # next input token
             "pos": jnp.full((S,), PARK_POS, jnp.int32),  # next decode pos
             "keys": jnp.zeros((S, 2), jnp.uint32),     # per-slot PRNG keys
             "temp": jnp.zeros((S,), jnp.float32),
-        }
+        })
 
     @staticmethod
     def _advance_state(state, logits):

@@ -124,16 +124,23 @@ class ServingFabric:
         self.dispatch_window = (int(dispatch_window) if dispatch_window
                                 else 2 * slots_per_rank)
 
+        # rank i owns local device i: its params, KV pool and dispatches
+        # live there (ranks past the device count wrap around and share
+        # the device and its one copy of the params)
+        local = jax.local_devices()
+        devices = [local[i % len(local)] for i in range(ranks)]
+        placed = {d: jax.device_put(params, d) for d in set(devices)}
         self.workers: List[EngineWorker] = []
         for i, role in enumerate(roles):
             eng = ContinuousEngine(
-                model, params, cache_len=cache_len,
+                model, placed[devices[i]], cache_len=cache_len,
                 num_slots=slots_per_rank, eos_id=eos_id, comm=subs[i],
                 prefill_chunk=prefill_chunk,
                 max_prefill_per_step=max_prefill_per_step,
                 kv_layout="paged", block_size=block_size,
                 num_blocks=blocks_per_rank, role=role,
-                speculate=self.speculate if role == "full" else 0)
+                speculate=self.speculate if role == "full" else 0,
+                device=devices[i])
             self.workers.append(EngineWorker(i, role, eng, comm=subs[i]))
 
         # -- the dispatch hop's admission queue (router rank) --
@@ -246,7 +253,8 @@ class ServingFabric:
                 t0 = time.perf_counter() if tr is not None else 0.0
                 try:
                     slot, dst_blocks = d.engine.begin_import(h.req)
-                    state_row = w.engine.handoff_state(h.slot)
+                    state_row = jax.device_put(
+                        w.engine.handoff_state(h.slot), d.device)
                     cost = self.transport.migrate(
                         w.engine.kv, d.engine.kv, h.blocks,
                         dst_blocks[:len(h.blocks)])

@@ -9,8 +9,10 @@ discipline end to end:
   (``ContinuousEngine.begin_import`` — the posted receive), so the
   lease is handed off rather than the prefill recomputed;
 * the prompt's KV then crosses **one block per message**: each hop is a
-  donated scatter of one source block into one destination block,
-  serialized on a dedicated ``CommStream`` (``kv-migrate``) and wrapped
+  donated scatter of one source block into one destination block (a
+  device-to-device copy when the ranks own different chips),
+  serialized on a dedicated ``CommStream`` per destination device
+  (``kv-migrate:<device id>``) and wrapped
   in a :class:`~repro.core.comm.Request` carrying the protocol model's
   request-object overhead for a message of one block — the exact
   ``isend``/``irecv`` pattern, with ``waitall`` as the completion point
@@ -47,10 +49,15 @@ class KVBlockTransport:
 
     def __init__(self, comm, stream_name: str = "kv-migrate"):
         self.comm = comm
-        self.stream = comm.stream(stream_name)
-        # one compiled program for every hop: scalar src/dst block ids,
-        # destination pool donated so XLA aliases it across the chain
-        self._copy = jax.jit(self._copy_impl, donate_argnums=(0,))
+        self.stream_name = stream_name
+        #: one migrate stream per destination device: a stream's program
+        #: order is a token array, which lives where its last block landed
+        self._streams = {}
+        # one compiled program per side for every hop: scalar block ids;
+        # the destination pool is donated so XLA aliases it across the
+        # chain
+        self._take = jax.jit(self._take_impl)
+        self._put = jax.jit(self._put_impl, donate_argnums=(0,))
         # accounting for the bench artifact's kv_migration rows
         self.n_migrations = 0
         self.n_blocks_moved = 0
@@ -58,24 +65,45 @@ class KVBlockTransport:
         self.modeled_cost_s = 0.0
 
     @staticmethod
-    def _copy_impl(dst, src, src_block, dst_block):
-        """One block message: scatter source block ``src_block`` of every
-        (L, P, bs, Gs, hd) leaf into destination block ``dst_block``.
+    def _take_impl(src, src_block):
+        """Source block ``src_block`` of every (L, P, bs, Gs, hd) leaf."""
+        return jax.tree_util.tree_map(
+            lambda s: jax.lax.dynamic_slice_in_dim(s, src_block, 1, axis=1),
+            src)
+
+    @staticmethod
+    def _put_impl(dst, block, dst_block):
+        """Scatter one taken block into destination block ``dst_block``.
         Also returns a 1-element completion probe read back out of the
         written block — the probe, not the pool, is what joins the
         stream and rides the Request (gating the full pool through an
         eager optimization_barrier would copy the whole un-donated pool
         once per block)."""
         new = jax.tree_util.tree_map(
-            lambda d, s: jax.lax.dynamic_update_slice_in_dim(
-                d, jax.lax.dynamic_slice_in_dim(
-                    s, src_block, 1, axis=1).astype(d.dtype),
-                dst_block, axis=1),
-            dst, src)
+            lambda d, b: jax.lax.dynamic_update_slice_in_dim(
+                d, b.astype(d.dtype), dst_block, axis=1),
+            dst, block)
         first = jax.tree_util.tree_leaves(new)[0]
         probe = jnp.ravel(jax.lax.dynamic_index_in_dim(
             first, dst_block, axis=1))[:1]
         return new, probe
+
+    def _copy(self, dst, src, src_block, dst_block):
+        """One block message: take the block on the source pool's device,
+        move it to the destination pool's device (a device-to-device copy
+        when the two ranks own different chips), scatter it there."""
+        block = self._take(src, src_block)
+        block = jax.device_put(
+            block, jax.tree_util.tree_leaves(dst)[0].sharding)
+        return self._put(dst, block, dst_block)
+
+    def _stream_to(self, dst_kv):
+        dev = next(iter(jax.tree_util.tree_leaves(dst_kv.buffers)[0]
+                        .devices()))
+        if dev not in self._streams:
+            self._streams[dev] = self.comm.stream(
+                f"{self.stream_name}:{dev.id}")
+        return self._streams[dev]
 
     @staticmethod
     def block_nbytes(kv) -> int:
@@ -102,6 +130,7 @@ class KVBlockTransport:
                 "token geometry)")
         nb = self.block_nbytes(src_kv)
         proto = protocol.select_protocol(nb, interthread=True)
+        stream = self._stream_to(dst_kv)
         requests: List[Request] = []
         dst = dst_kv.buffers
         san = _san_active()
@@ -126,10 +155,10 @@ class KVBlockTransport:
                 # is the block's completion point; the pool itself is
                 # serialized by the donation chain and must not be
                 # pinned by a request (the next hop deletes it)
-                probe = self.stream.ordered(probe)
+                probe = stream.ordered(probe)
                 requests.append(Request(
                     self.comm, f"kv_block[{proto}]", probe,
-                    stream=self.stream,
+                    stream=stream,
                     model_overhead_s=protocol.request_overhead(nb, proto)))
         finally:
             # request-leak: completion must sit on the exception path

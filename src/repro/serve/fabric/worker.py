@@ -15,6 +15,8 @@ from __future__ import annotations
 
 from typing import Dict, List
 
+import jax
+
 from repro.core import protocol
 # telemetry (REPRO_TRACE=1, DESIGN.md §15): each rank step runs inside a
 # rank scope so every span a pool thread emits lands on the right
@@ -34,6 +36,10 @@ class EngineWorker:
         self.role = role
         self.engine = engine
         self.comm = comm
+        #: the device this rank runs on (the router gives rank i local
+        #: device i; None = JAX's default). The per-step inputs the
+        #: engine builds land there directly, not by way of device 0.
+        self.device = engine.device
         # -- per-rank accounting (the router's utilization rows) --
         self.total_steps = 0
         self.busy_steps = 0
@@ -140,13 +146,14 @@ class EngineWorker:
     def step(self, now: float = 0.0) -> List[ServeRequest]:
         busy = not self.idle
         tr = _tr_active()
-        if tr is None:
-            finished = self.engine.step(now)
-        else:
-            with tr.rank_scope(self.rank), \
-                    tr.span("rank_step", cat="fabric", rank=self.rank,
-                            role=self.role, busy=busy):
+        with jax.default_device(self.device):
+            if tr is None:
                 finished = self.engine.step(now)
+            else:
+                with tr.rank_scope(self.rank), \
+                        tr.span("rank_step", cat="fabric", rank=self.rank,
+                                role=self.role, busy=busy):
+                    finished = self.engine.step(now)
         self.total_steps += 1
         self.busy_steps += int(busy)
         self.n_finished += len(finished)

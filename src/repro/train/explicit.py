@@ -48,7 +48,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config import MeshConfig, TrainConfig
 from repro.core.comm import threadcomm_init
-from repro.core.compat import HAS_PARTIAL_MANUAL, shard_map
+from repro.core.compat import shard_map
 from repro.dist.sharding import batch_pspec, named_sharding, param_pspecs
 from repro.optim import cosine_schedule
 
@@ -196,18 +196,14 @@ def make_explicit_train_step(model, mesh_cfg: MeshConfig, tcfg: TrainConfig,
             opt=FlatAdamState(step=step, m=m, v=v, master=new_master))
         return new_state, metrics
 
-    # manual over the unified DP rank space; "model" stays auto (TP) where
-    # the jax/XLA stack supports partial-manual regions. Old XLA miscompiles
-    # all-gather/ppermute inside manual subgroups, so there we take the
-    # whole mesh manual: TP-degree-redundant compute, identical numerics.
+    # manual over the unified DP rank space; "model" stays auto (TP)
     shard_spec = P(thread_axes) if thread_axes else P()
     state_in_specs = ExplicitTrainState(
         params=jax.tree_util.tree_map(lambda _: P(), model_params_struct(model)),
         opt=FlatAdamState(step=P(), m=shard_spec, v=shard_spec,
                           master=shard_spec))
-    manual_axes = set(dp_axes) if HAS_PARTIAL_MANUAL else None
     mapped = shard_map(
-        inner, mesh=mesh, axis_names=manual_axes,
+        inner, mesh=mesh, axis_names=set(dp_axes),
         in_specs=(state_in_specs, P(dp_axes)),
         out_specs=(state_in_specs, P()), check_vma=False)
 

@@ -743,6 +743,78 @@ def case_comm_waitall_mixed():
     print("CASE-OK")
 
 
+def case_fabric_rank_devices():
+    """Fabric ranks on their own devices: rank i's params, KV pool and
+    decode state live on local device i, under both placements (disagg
+    migrates KV blocks device to device), and every greedy token matches
+    a single engine on device 0."""
+    from repro.config import ServeConfig, TrainConfig
+    from repro.configs import get_smoke_config
+    from repro.models.registry import build_model, make_synthetic_batch
+    from repro.serve import (ContinuousEngine, ServeRequest, ServingFabric,
+                             make_trace)
+
+    n = len(jax.devices())
+    cfg = get_smoke_config("gemma-2b")
+    model = build_model(cfg, TrainConfig(param_dtype="float32",
+                                         compute_dtype="float32",
+                                         remat=False), ServeConfig(), tp=1)
+    params = model.init(jax.random.PRNGKey(0))
+    trace = make_trace(8, prompt_len=(16, 40), max_new=(3, 8),
+                       arrival="all", seed=0)
+
+    def requests():
+        return [ServeRequest(
+            rid=rid, batch={"tokens": np.asarray(make_synthetic_batch(
+                cfg, 1, e.prompt_len, seed=1000 + rid,
+                compute_dtype="float32")["tokens"])},
+            max_new_tokens=e.max_new, temperature=0.0, seed=0)
+            for rid, e in enumerate(trace)]
+
+    def drain(target, reqs):
+        for r in reqs:
+            target.submit(r, 0.0)
+        for _ in range(4000):
+            if target.idle:
+                break
+            target.step(0.0)
+        assert target.idle, "failed to drain"
+        return [r.output[:r.generated].copy() for r in reqs]
+
+    kw = dict(cache_len=48, prefill_chunk=16, max_prefill_per_step=2,
+              block_size=8)
+    ref = drain(ContinuousEngine(model, params, num_slots=4,
+                                 kv_layout="paged", **kw), requests())
+    for placement in ("replicated", "disagg"):
+        fab = ServingFabric(model, params, ranks=n, placement=placement,
+                            slots_per_rank=4, **kw)
+        try:
+            out = drain(fab, requests())
+            assert all(np.array_equal(a, b) for a, b in zip(ref, out)), \
+                placement
+            for i, w in enumerate(fab.workers):
+                want = {jax.devices()[i]}
+                assert w.device == jax.devices()[i]
+                assert w.engine.params["embed"].devices() == want
+                for leaf in jax.tree_util.tree_leaves(w.engine.kv.buffers):
+                    assert leaf.devices() == want, (placement, i)
+            if placement == "disagg":
+                assert fab.transport.n_migrations == len(trace)
+        finally:
+            fab.close()
+    print("CASE-OK")
+
+
+def case_chip_smoke_four():
+    """chip_smoke.py's four-chip phase at the smoke config: the replicated
+    fabric, rank i on device i, token-identical to one paged engine."""
+    import chip_smoke
+    r = chip_smoke.serve_four_chips(smoke=True)
+    assert r["identical_requests"] == r["requests"] == chip_smoke.ROWS
+    assert len({row["device"] for row in r["per_rank"]}) == 4
+    print("CASE-OK")
+
+
 CASES = {k[5:]: v for k, v in list(globals().items())
          if k.startswith("case_")}
 

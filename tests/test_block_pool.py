@@ -70,7 +70,7 @@ def test_blocks_needed_matches_token_count(ntokens, block_size):
 
 class _StubModel:
     @staticmethod
-    def init_paged_cache(num_blocks, block_size, dtype=None):
+    def init_paged_cache(num_blocks, block_size, dtype=None, num_rows=0):
         return {"k": np.zeros((1, num_blocks, block_size, 1, 1)),
                 "v": np.zeros((1, num_blocks, block_size, 1, 1))}
 

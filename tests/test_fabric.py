@@ -17,6 +17,7 @@ from repro.serve import (ContinuousEngine, ServeRequest, ServingFabric,
                          make_trace)
 from repro.serve.fabric.placement import make_placement
 from repro.serve.fabric.transport import KVBlockTransport
+from tests.helpers import run_case
 
 TRAIN = TrainConfig(param_dtype="float32", compute_dtype="float32",
                     loss_chunk=16, attn_chunk_threshold=64, attn_chunk=16,
@@ -363,6 +364,13 @@ def test_prefill_role_leases_prompt_only(bundle):
     assert taken == [h] and not eng.ready_handoffs
     eng.release_handoff(h.slot)
     assert eng.kv.pool.num_free == eng.kv.pool.num_blocks
+
+
+def test_ranks_run_on_their_own_devices():
+    """On four devices rank i's params and KV pool live on device i,
+    under both placements (disagg copies KV blocks device to device),
+    token-identical to one engine on device 0."""
+    run_case("fabric_rank_devices", ndev=4, timeout=600)
 
 
 # ---------------------------------------------------------------------------

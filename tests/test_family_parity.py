@@ -5,8 +5,9 @@ bit-exactly at any chunk boundary.
 
 Three layers of evidence:
 
-* chunked deposit vs monolithic prefill produce the identical
-  carried-state pytree and the identical first token for SSM and hybrid
+* chunked deposit vs monolithic prefill produce the same carried-state
+  pytree (to float32 matmul rounding) and the same first token (to a
+  logit margin) for SSM and hybrid
   — a hypothesis property over random prompt lengths / chunk sizes
   (``ssm_chunk`` multiples) when hypothesis is installed, plus a
   deterministic seeded sweep that always runs;
@@ -69,6 +70,26 @@ def _chunked_deposit(model, params, tokens, chunk, cache_len):
     return int(jnp.argmax(logits)), cache
 
 
+#: Carried state is compared to a tolerance, not bit for bit. The conv
+#: leaf is raw ``in_proj`` output, and a float32 matmul over a C-row
+#: chunk accumulates in a different order than one over the whole
+#: S-row prompt (the backend tiles by M), so rows differ by a few ulps
+#: (measured <= 1.4e-6 absolute on CPU). The hybrid's attention layers
+#: add the same kind of reassociation noise. A logic error (a lost or
+#: shifted row, a wrong decay grid) moves values by O(1e-2) or more.
+STATE_RTOL, STATE_ATOL = 1e-4, 1e-5
+#: First-token logits agree to ~2.5e-6 (measured); a chunked token is
+#: accepted when its logit is within this margin of the reference
+#: argmax, so a near-tie between random-init logits cannot flip the test.
+LOGIT_MARGIN = 1e-4
+
+
+def _assert_token_within_margin(tok, logits_ref, what):
+    ref = np.asarray(logits_ref, np.float32).ravel()
+    assert ref[tok] >= ref.max() - LOGIT_MARGIN, (
+        what, tok, int(ref.argmax()), float(ref.max() - ref[tok]))
+
+
 def _assert_chunked_matches_monolithic(arch, S, chunk, seed):
     cfg, model, params = _bundle(arch)
     m = model.capabilities.chunk_multiple
@@ -76,42 +97,33 @@ def _assert_chunked_matches_monolithic(arch, S, chunk, seed):
     rng = np.random.default_rng(seed)
     tokens = rng.integers(0, cfg.vocab_size, (1, S)).astype(np.int32)
     leaves = model.capabilities.state_leaves
+    what = (arch, S, chunk, seed)
 
     logits_m, cache_m = model.prefill(params, {"tokens": jnp.asarray(tokens)},
                                       cache_len)
-    tok_m = int(jnp.argmax(logits_m[0]))
     tok_c, cache_c = _chunked_deposit(model, params, tokens, chunk, cache_len)
-    assert tok_c == tok_m, (arch, S, chunk, seed)
+    _assert_token_within_margin(tok_c, logits_m[0], what)
 
     # the state-threading contract: resuming the scan at a DIFFERENT
-    # chunk grid deposits the identical carried state, bit for bit
+    # chunk grid deposits the same carried state (to matmul rounding)
     other = 2 * m if chunk == m else m
     tok_o, cache_o = _chunked_deposit(model, params, tokens, other, cache_len)
-    assert tok_o == tok_c
+    _assert_token_within_margin(tok_o, logits_m[0], what)
     for leaf in leaves:
-        np.testing.assert_array_equal(
+        np.testing.assert_allclose(
             np.asarray(cache_c[leaf]), np.asarray(cache_o[leaf]),
+            rtol=STATE_RTOL, atol=STATE_ATOL,
             err_msg=f"carried-state leaf {leaf!r} depends on the chunk "
                     f"grid ({arch}, S={S}, {chunk} vs {other}, seed={seed})")
 
-    # vs the monolithic oracle: pure SSM is bit-exact (same scan
-    # implementation both paths); the hybrid's attention layers
-    # accumulate in a different order in full-sequence prefill than in
-    # cached-chunk deposit, so the state the downstream SSM blocks see
-    # carries float32 reassociation noise — bounded, not a logic bug
-    exact = cfg.block == "ssm"
+    # vs the monolithic oracle: same tolerance and the same reason
     for leaf in leaves:
-        a, b = np.asarray(cache_m[leaf]), np.asarray(cache_c[leaf])
-        if exact:
-            np.testing.assert_array_equal(
-                a, b, err_msg=f"carried-state leaf {leaf!r} diverged "
-                              f"({arch}, S={S}, chunk={chunk}, seed={seed})")
-        else:
-            np.testing.assert_allclose(
-                a, b, rtol=1e-3, atol=1e-5,
-                err_msg=f"carried-state leaf {leaf!r} diverged beyond "
-                        f"float32 reassociation noise "
-                        f"({arch}, S={S}, chunk={chunk}, seed={seed})")
+        np.testing.assert_allclose(
+            np.asarray(cache_m[leaf]), np.asarray(cache_c[leaf]),
+            rtol=STATE_RTOL, atol=STATE_ATOL,
+            err_msg=f"carried-state leaf {leaf!r} diverged beyond float32 "
+                    f"matmul rounding ({arch}, S={S}, chunk={chunk}, "
+                    f"seed={seed})")
 
 
 @pytest.mark.parametrize("arch", ["mamba2-370m", "hymba-1.5b"])

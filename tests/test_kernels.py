@@ -1,19 +1,29 @@
 """Pallas kernel validation: interpret=True execution vs pure-jnp oracles,
 swept over shapes and dtypes (per-kernel allclose requirement)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention.ref import flash_attention_ref
-from repro.kernels.msgq.ops import copy_accounting, msgq_copy
+from repro.kernels.msgq import ops as msgq_ops
+from repro.kernels.msgq.ops import copy_accounting
 from repro.kernels.msgq.ref import msgq_copy_ref
-from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention import ops as paged_ops
 from repro.kernels.paged_attention.ref import paged_attention_ref
-from repro.kernels.ssd_scan.ops import ssd_scan
+from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.ssd_scan.ref import ssd_scan_ref
+
+# the wrappers compile for the TPU by default; here every kernel body
+# runs in the Pallas interpreter on the CPU
+flash_attention = functools.partial(flash_ops.flash_attention, interpret=True)
+msgq_copy = functools.partial(msgq_ops.msgq_copy, interpret=True)
+paged_attention = functools.partial(paged_ops.paged_attention, interpret=True)
+ssd_scan = functools.partial(ssd_ops.ssd_scan, interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -258,17 +268,19 @@ def test_paged_attention_mq_matches_ref(B, H, Hkv, hd, P, bs, NB, K):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_paged_attention_mq_k1_bit_identical_to_single():
-    """The q-block kernel at K=1 must reduce to the single-token kernel
-    BIT-EXACTLY — same loop structure, same accumulation order — so a
-    speculative engine at k=1 prices and computes like the plain one."""
-    from repro.kernels.paged_attention.paged_attention import (
-        paged_attention_fwd,
-    )
-    q, kp, vp, bt, ln = _paged_inputs(3, 4, 2, 32, 16, 16, 4, seed=5)
-    single = paged_attention_fwd(q, kp, vp, bt, ln, interpret=True)
-    mq = paged_attention_fwd(q[:, None], kp, vp, bt, ln, interpret=True)
-    np.testing.assert_array_equal(np.asarray(mq[:, 0]), np.asarray(single))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_mq_k1_bit_identical_to_single(dtype):
+    """A (B, H, hd) single query and the same query as a (B, 1, H, hd)
+    q-block must agree BIT-EXACTLY — both run the one q-block kernel at
+    K=1, so a speculative engine at k=1 prices and computes like the
+    plain one."""
+    q, kp, vp, bt, ln = _paged_inputs(3, 4, 2, 32, 16, 16, 4, seed=5,
+                                      dtype=dtype)
+    single = paged_attention(q, kp, vp, bt, ln)
+    mq = paged_attention(q[:, None], kp, vp, bt, ln)
+    assert single.shape == q.shape and mq.shape == (3, 1, 4, 32)
+    np.testing.assert_array_equal(np.asarray(mq[:, 0], np.float32),
+                                  np.asarray(single, np.float32))
 
 
 def test_paged_attention_mq_identity_table_matches_flash():

@@ -214,6 +214,33 @@ def test_paged_engine_reset_restores_pool():
     assert out.shape == (2, 4)
 
 
+def test_pinned_engine_compiles_once_across_reset():
+    """An engine pinned to a device (a fabric rank on its chip) keeps
+    params, pool and decode state committed there across reset, so a
+    second identical run after reset compiles nothing."""
+    cfg, model, params = _bundle()
+    dev = jax.devices()[0]
+    eng = _paged(model, jax.device_put(params, dev), device=dev)
+    prompt = _prompt(cfg, B=2, S=8)
+    first = eng.generate(prompt, 4)
+    eng.reset()
+    compiles = []
+
+    def listener(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        second = eng.generate(prompt, 4)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert np.array_equal(first, second)
+    assert compiles == []
+    for leaf in jax.tree_util.tree_leaves((eng.kv.buffers, eng._state)):
+        assert leaf.devices() == {dev}
+
+
 def test_paged_oversized_request_rejected_at_submit():
     """A request whose prompt+max_new can never fit its block table must
     fail loudly at submit — not crash the serve loop from the admission

@@ -556,18 +556,17 @@ _SPAN_COMPLETE_OPS = frozenset({"end", "end_span"})
 
 
 class SpanLeakRule(RequestLeakRule):
-    """Same AST shape as request-leak, retargeted at the tracer's
-    manual span API (DESIGN.md §15): a handle from ``tr.span(...)`` /
-    ``tr.begin_span(...)`` bound to a local name must reach ``end()``
-    on every path. Context-manager use (``with tr.span(...):``) and
-    handles that escape (returned, stored on ``self``, passed on) are
-    exception-safe or owned elsewhere and never flagged — exactly the
-    request-leak escape semantics. A leaked span corrupts the tracer's
-    thread-local nesting stack, mis-parenting every later span on that
-    thread."""
+    """Same AST shape as request-leak, retargeted at the manual span
+    API (DESIGN.md §15): a handle from ``obs_trace.span(...)`` bound to
+    a local name must reach ``end()`` on every path. Context-manager use
+    (``with obs_trace.span(...):``) and handles that escape (returned,
+    stored on ``self``, passed on) are exception-safe or owned elsewhere
+    and never flagged — exactly the request-leak escape semantics. A
+    span entered and never ended leaves its profiler annotation open and
+    its ``rank=`` on the thread, mis-attributing everything after it."""
 
     name = "span-leak"
-    summary = ("a manually-bound tracer span must reach end() on every "
+    summary = ("a manually-bound span must reach end() on every "
                "path (or be opened as a context manager)")
 
     _issue_attrs = _SPAN_ISSUE_OPS
@@ -578,19 +577,20 @@ class SpanLeakRule(RequestLeakRule):
 
     def _msg_discard(self) -> str:
         return ("Span discarded at the call site: it opens on the "
-                "tracer's stack and is never ended — use "
-                "`with tr.span(...):` or bind the handle and end() it")
+                "thread's span stack and is never ended — use "
+                "`with obs_trace.span(...):` or bind the handle and "
+                "end() it")
 
     def _msg_leak(self, name: str) -> str:
         return (f"Span bound to `{name}` is never ended: no end() "
                 "reaches it in this function and it does not escape — "
-                "the tracer's nesting stack leaks")
+                "the thread's span nesting leaks")
 
     def _msg_exception(self, name: str) -> str:
         return (f"Spans bound to `{name}` are opened inside a try body "
                 "and only ended there: an exception leaves them on the "
-                "tracer's stack — move the end() into the finally "
-                "block (or use `with tr.span(...):`)")
+                "thread's span stack — move the end() into the finally "
+                "block (or use `with obs_trace.span(...):`)")
 
 
 # ---------------------------------------------------------------------------

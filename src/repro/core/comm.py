@@ -34,7 +34,6 @@ them afterwards raises :class:`ThreadCommError`.
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -47,9 +46,8 @@ from jax.sharding import PartitionSpec as P
 # runtime threadcomm sanitizer (REPRO_SANITIZE=1, DESIGN.md §11): every
 # hook below is a single global read + None check when disabled
 from repro.analysis.sanitizer import active as _san_active
-# span tracer + stall detector (REPRO_TRACE=1, DESIGN.md §15) — the
-# same one-global-read-plus-None-check discipline when disabled
-from repro.obs.trace import active as _tr_active
+# profiler spans + the stall detector (DESIGN.md §15)
+from repro.obs import trace as obs_trace
 from repro.core import collectives as coll
 from repro.core import p2p as p2p_mod
 from repro.core import protocol
@@ -116,16 +114,12 @@ class Request:
         value = self._value
         leaves = jax.tree_util.tree_leaves(value)
         if not any(isinstance(l, jax.core.Tracer) for l in leaves):
-            tr = _tr_active()
-            if tr is None:
+            # the completion point is where accidental serialization
+            # bites: time the block, and let the stall detector charge
+            # it when this thread had runnable work
+            with obs_trace.span("comm.wait", op=self.op) as sp:
                 jax.block_until_ready(value)   # host-level completion
-            else:
-                # the completion point is where accidental serialization
-                # bites: time the block, and let the stall detector
-                # charge it when this thread had runnable work
-                t0 = time.perf_counter()
-                jax.block_until_ready(value)   # host-level completion
-                tr.on_wait(self.op, t0, time.perf_counter())
+            obs_trace.on_wait(sp.seconds)
         return value
 
     def test(self) -> Tuple[bool, Optional[object]]:
@@ -182,9 +176,9 @@ class CommStream:
         san = _san_active()
         if san is not None:       # program order flows into the stream
             san.on_stream_enter(self)
-        tr = _tr_active()
-        if tr is not None:        # stream-region span, closed in __exit__
-            self._obs_span = tr.span(f"stream:{self.name}", cat="comm")
+        # stream-region span, ended in __exit__
+        self._obs_span = obs_trace.span("comm.stream", stream=self.name)
+        self._obs_span.__enter__()
         self.comm._root._stream_stack.append(self)
         return self
 

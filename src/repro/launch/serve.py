@@ -160,15 +160,15 @@ def _drive_wall_clock(target, requests: List[ServeRequest]) -> float:
 
 
 def _attach_telemetry(stats: Dict) -> None:
-    """When the tracer is live (``REPRO_TRACE=1``), stamp the trial's
+    """When the residual ledger is on (``REPRO_TRACE=1``), stamp the trial's
     residual report, flat per-hop ratios, and the serialization-stall
     total onto the stats dict. The capture is trial-clean because every
     warm-up boundary (``engine.reset`` / ``fabric.close``) flushes the
     ledger before the measured drive starts."""
-    tr = obs_trace.active()
-    if tr is None:
+    led = obs_trace.ledger()
+    if led is None:
         return
-    rep = tr.residuals.report()
+    rep = led.report()
     stats["residual_report"] = rep
     for kind, row in rep["hops"].items():
         if row["n"]:
@@ -807,19 +807,6 @@ def _finalize_payload(payload: Dict) -> Dict:
     return payload
 
 
-def _write_trace(path) -> None:
-    """``--trace-out``: export the tracer's ring as Chrome trace_event
-    JSON (Perfetto / chrome://tracing)."""
-    if not path:
-        return
-    tr = obs_trace.active()
-    if tr is None:
-        print(f"--trace-out {path}: tracing is off (set REPRO_TRACE=1)")
-        return
-    tr.write_chrome(path)
-    print(f"wrote {path} ({tr.n_events} events, {tr.dropped} dropped)")
-
-
 def main():
     use_compile_cache()
     ap = argparse.ArgumentParser()
@@ -892,11 +879,25 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write measurements (e.g. BENCH_serve.json)")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write the telemetry ring as Chrome trace_event "
-                         "JSON for Perfetto (needs REPRO_TRACE=1)")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="profile the run with jax.profiler into DIR: "
+                         "host spans and device ops on one timeline, "
+                         "with a Perfetto trace (perfetto_trace.json.gz)")
     args = ap.parse_args()
+    if not args.trace_out:
+        _run(args, ap)
+        return
+    # spans and device ops; tracing every Python call would swamp them
+    # (the Perfetto export keeps at most a million events)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(args.trace_out, create_perfetto_trace=True,
+                            profiler_options=opts):
+        _run(args, ap)
+    print(f"wrote a profiler trace under {args.trace_out}")
 
+
+def _run(args, ap) -> None:
     plens = [int(x) for x in str(args.prompt_len).split(",") if x]
     if args.config is not None:
         archs = (FAMILY_ARCHS if args.config in ("families", "all")
@@ -926,7 +927,6 @@ def main():
             with open(args.json, "w") as f:
                 json.dump(payload, f, indent=1)
             print(f"wrote {args.json}")
-        _write_trace(args.trace_out)
         return
 
     if args.fabric != "off":
@@ -981,7 +981,6 @@ def main():
             with open(args.json, "w") as f:
                 json.dump(payload, f, indent=1)
             print(f"wrote {args.json}")
-        _write_trace(args.trace_out)
         return
 
     result = run_traffic(
@@ -1069,7 +1068,6 @@ def main():
         with open(args.json, "w") as f:
             json.dump(payload, f, indent=1)
         print(f"wrote {args.json}")
-    _write_trace(args.trace_out)
 
 
 if __name__ == "__main__":

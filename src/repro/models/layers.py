@@ -73,6 +73,7 @@ def layernorm(x, w, b, *, eps: float = 1e-5):
     return (y * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def apply_norm(x, p, cfg):
     if cfg.norm_type == "layernorm":
         return layernorm(x, p["w"], p["b"], eps=cfg.norm_eps)
@@ -317,6 +318,7 @@ def init_mlp(cfg, key, dtype=jnp.float32):
             "w_down": dense_init(ks[1], (f, d), f, dtype)}
 
 
+@jax.named_scope("mlp")
 def mlp_apply(p, x, cfg):
     if cfg.mlp_act in ("swiglu", "geglu"):
         g = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(x.dtype))

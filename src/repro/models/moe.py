@@ -78,6 +78,7 @@ def _route_group(p, xg, cfg):
     return out, (lb_loss, z_loss, dropped)
 
 
+@jax.named_scope("moe")
 def moe_apply(p, x, cfg):
     """x (B, S, d) -> (out (B, S, d), aux dict). Groups follow batch sharding.
     Ragged token counts are zero-row padded up to a group multiple (padded
@@ -99,6 +100,7 @@ def moe_apply(p, x, cfg):
     return out.reshape(B, S, d), aux
 
 
+@jax.named_scope("moe")
 def moe_apply_dropless(p, x, cfg):
     """Serve-time routing: per-token top-k with no capacity coupling.
 

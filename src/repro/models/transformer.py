@@ -190,6 +190,7 @@ def block_forward(cfg, p, x, positions, is_global, knobs, *,
 # Backbone
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def embed_tokens(cfg, params, tokens, compute_dtype):
     x = jnp.take(params["embed"], tokens, axis=0).astype(compute_dtype)
     if cfg.embed_scale:
@@ -225,6 +226,16 @@ def lm_head_weight(cfg, params):
     if cfg.tie_embeddings:
         return params["embed"].T
     return params["lm_head"]
+
+
+@jax.named_scope("lm_head")
+def _paged_logits(cfg, params, hidden, compute_dtype):
+    """float32 logits of ``hidden`` (..., d) over the padded vocabulary,
+    padding entries masked: the tail of every paged program."""
+    w_out = lm_head_weight(cfg, params).astype(compute_dtype)
+    logits = (hidden @ w_out).astype(jnp.float32)
+    vocab_ok = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
+    return jnp.where(vocab_ok, logits, L.NEG_INF)
 
 
 def _layer_slice(tree, idx):
@@ -539,6 +550,7 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return c
 
 
+@jax.named_scope("attention")
 def _paged_attn(cfg, p, xn, layer_cache, tables, qpos, wvalid, is_global):
     """Attention for query tokens against (and into) the paged pool — the
     block-table analogue of :func:`_cached_attn`, batched across requests.
@@ -658,9 +670,12 @@ def _paged_backbone(cfg, params, x, tables, qpos, wvalid, cache, flags, *,
             h = h + m_out
         return (h, _layer_put(cch, new_cache, idx)), None
 
-    (x, new_cache), _ = lax.scan(
-        body, (x, cache),
-        (params["blocks"], flags, jnp.arange(cfg.num_layers)))
+    # the scan's own per-layer slicing of weights and pool lands under
+    # "layers"; each sublayer's ops under its own scope within it
+    with jax.named_scope("layers"):
+        (x, new_cache), _ = lax.scan(
+            body, (x, cache),
+            (params["blocks"], flags, jnp.arange(cfg.num_layers)))
     return L.apply_norm(x, params["final_norm"], cfg), new_cache
 
 
@@ -681,10 +696,8 @@ def make_decode_step_paged(cfg: ModelConfig, knobs, tp: int):
         wvalid = (positions >= 0)[:, None]
         x, new_cache = _paged_backbone(cfg, params, x, block_tables, qpos,
                                        wvalid, cache, flags)
-        w_out = lm_head_weight(cfg, params).astype(compute_dtype)
-        logits = (x[:, 0, :] @ w_out).astype(jnp.float32)
-        vocab_ok = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
-        return jnp.where(vocab_ok, logits, L.NEG_INF), new_cache
+        return _paged_logits(cfg, params, x[:, 0, :], compute_dtype), \
+            new_cache
 
     return decode_step
 
@@ -723,10 +736,7 @@ def make_verify_step_paged(cfg: ModelConfig, knobs, tp: int):
                   & (positions >= 0)[:, None])
         x, new_cache = _paged_backbone(cfg, params, x, block_tables, qpos,
                                        wvalid, cache, flags)
-        w_out = lm_head_weight(cfg, params).astype(compute_dtype)
-        logits = jnp.einsum("bkd,dv->bkv", x, w_out).astype(jnp.float32)
-        vocab_ok = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
-        return jnp.where(vocab_ok[None, None], logits, L.NEG_INF), new_cache
+        return _paged_logits(cfg, params, x, compute_dtype), new_cache
 
     return verify_step
 
@@ -758,10 +768,7 @@ def make_prefill_chunk_paged(cfg: ModelConfig, knobs, tp: int):
                                        rows=rows, pos0=pos0, n_valid=n_valid)
         last = jnp.clip(n_valid - 1, 0, C - 1)
         hidden = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-        w_out = lm_head_weight(cfg, params).astype(compute_dtype)
-        logits = (hidden @ w_out).astype(jnp.float32)
-        vocab_ok = jnp.arange(cfg.padded_vocab) < cfg.vocab_size
-        return jnp.where(vocab_ok, logits, L.NEG_INF), new_cache
+        return _paged_logits(cfg, params, hidden, compute_dtype), new_cache
 
     return prefill_chunk
 

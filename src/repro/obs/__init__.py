@@ -1,13 +1,13 @@
-"""Serving-fabric observability (DESIGN.md §15): span tracing, metrics
-registry, and modeled-vs-measured cost residuals.
+"""Serving-fabric observability (DESIGN.md §15): host spans on the
+profiler's clock, a metrics registry, and modeled-vs-measured cost
+residuals.
 
-Off by default and compiled out of the hot path when off — every
-instrumented site is one module-global read plus a ``None`` check, the
-sanitizer's pattern (DESIGN.md §11). ``REPRO_TRACE=1`` (or
-:func:`install`) turns on the whole subsystem: the span tracer
-(:mod:`repro.obs.trace`), the push-metrics registry
-(:mod:`repro.obs.metrics`), and the residual ledger the tracer owns
-(:mod:`repro.obs.residuals`).
+Spans (:mod:`repro.obs.trace`) are ``jax.profiler`` annotations: always
+emitted, recorded only under a profiler session. The push-metrics
+registry (:mod:`repro.obs.metrics`) and the residual ledger
+(:mod:`repro.obs.residuals`) are state, off by default: one module-global
+read plus a ``None`` check when off, the sanitizer's pattern (DESIGN.md
+§11). ``REPRO_TRACE=1`` (or :func:`install`) turns both on.
 """
 
 from __future__ import annotations
@@ -15,14 +15,14 @@ from __future__ import annotations
 from repro.obs import metrics, residuals, trace
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.residuals import ResidualLedger, merge_reports
-from repro.obs.trace import Span, Tracer
+from repro.obs.trace import Span, span
 
 
-def install(capacity: int = trace.DEFAULT_CAPACITY) -> Tracer:
-    """Turn on the full subsystem (tracer + registry); returns the
-    tracer. Equivalent to launching under ``REPRO_TRACE=1``."""
+def install() -> ResidualLedger:
+    """Turn on the registry and the residual ledger; returns the ledger.
+    Equivalent to launching under ``REPRO_TRACE=1``."""
     metrics.install()
-    return trace.install(capacity=capacity)
+    return trace.install()
 
 
 def uninstall() -> None:
@@ -39,7 +39,6 @@ def flush_trial() -> None:
 
 
 __all__ = [
-    "MetricsRegistry", "ResidualLedger", "Span", "Tracer",
-    "flush_trial", "install", "merge_reports", "metrics", "residuals",
-    "trace", "uninstall",
+    "MetricsRegistry", "ResidualLedger", "Span", "flush_trial", "install",
+    "merge_reports", "metrics", "residuals", "span", "trace", "uninstall",
 ]

@@ -4,9 +4,9 @@ Two surfaces in one module:
 
 **Push registry** — counters, gauges and histograms instrumented sites
 update live (scheduler admissions, TTFT observations, block-pool
-occupancy, queue depth). Enabled with the tracer (``REPRO_TRACE=1``) or
-:func:`install`; disabled, every site is the sanitizer's one-global-
-read-plus-None-check. ``snapshot()`` renders the registry as one plain
+occupancy, queue depth). Enabled with the residual ledger
+(``REPRO_TRACE=1``) or :func:`install`; disabled, every site is the
+sanitizer's one-global-read-plus-None-check. ``snapshot()`` renders the registry as one plain
 dict; ``reset()`` is the trial flush (wired into engine/fabric resets
 so a warm trial's observations never aggregate into a measured one).
 
@@ -288,8 +288,8 @@ def snapshot(engine=None, scheduler=None, workers: Iterable = (),
 
 
 # ---------------------------------------------------------------------------
-# Global activation — sanitizer pattern; REPRO_TRACE turns on the whole
-# obs subsystem (tracer + registry) with one switch.
+# Global activation — sanitizer pattern; REPRO_TRACE turns on the
+# registry and the residual ledger with one switch.
 # ---------------------------------------------------------------------------
 
 _REG: Optional[MetricsRegistry] = None

@@ -2,8 +2,9 @@
 
 Every serving hop the §3.2 protocol model prices — admission, prefix
 hit, KV migration, speculative verify round, router dispatch — has a
-wall-clock twin the tracer measures at the same site. The ledger keeps
-the (modeled, measured) pairs per hop kind, and :meth:`residual_report`
+wall-clock twin: the span around the hop
+(:meth:`repro.obs.trace.Span.hop`). The ledger keeps the (modeled,
+measured) pairs per hop kind, and :meth:`residual_report`
 surfaces where the model is off by more than a factor (default 2×):
 that divergence is the observability the paper's §2 pathology demands —
 a hop whose measured cost dwarfs its modeled one is where threads are
@@ -12,8 +13,8 @@ serializing on shared communication state.
 The ledger also owns the **serialization-stall detector**: time a rank
 spends blocked inside a comm completion (``Request.wait`` /
 ``waitall``) while it *has runnable work* (live decode rows, queued
-requests — the tracer's thread-local runnable hint, set by the engine
-at each micro-step). Blocked-while-runnable is the paper's accidental
+requests — the thread-local runnable hint of :mod:`repro.obs.trace`,
+set by the engine at each step). Blocked-while-runnable is the paper's accidental
 serialization, measured instead of inferred.
 
 Everything here is trial-scoped: drivers flush the ledger at warm-up

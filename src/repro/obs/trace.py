@@ -1,349 +1,151 @@
-"""Span tracer for the serving fabric (``REPRO_TRACE=1``, DESIGN.md §15).
+"""Spans on the profiler's clock (DESIGN.md §15).
 
-Nestable spans with thread/rank/stream context over everything the
-paper's threading story touches: comm ops and ``CommStream`` regions,
-engine micro-steps (``prefill_chunk`` / ``decode`` / ``spec_round``),
-scheduler admit/defer decisions, and the fabric's dispatch/migrate
-hops. Events land in a bounded ring buffer (overflow drops oldest
-first) and export as Chrome ``trace_event`` JSON, so a whole fabric
-trial opens in Perfetto (or ``chrome://tracing``) as one per-rank
-timeline — each engine rank a lane, its chunk/decode/verify dispatches
-and migrations laid out against the router's hops.
+One helper, :func:`span`, wraps ``jax.profiler.TraceAnnotation``. Under
+a profiler session (``jax.profiler.trace``, ``launch/serve.py
+--trace-out``, the chip benchmark's traced window) each span is an event
+of the profile's host plane, on the same timeline as the device's
+operations, with its keyword arguments as the event's arguments. With no
+session the annotation records nothing and costs about a microsecond, so
+spans have no switch. A span also reads ``perf_counter`` at both ends:
+``phases=`` adds its wall time to a dict, which the engine keeps for its
+latest step whether or not a profiler runs.
 
-Cost discipline mirrors the sanitizer (DESIGN.md §11): disabled, every
-instrumented site is one module-global read plus a ``None`` check —
-nothing allocates, nothing reads the clock. Enabled, the hot-path API
-is ``complete(name, t0, t1)``: the caller reads ``perf_counter`` around
-the timed region and the tracer records a single pre-timed "X" event
-(no begin/end bookkeeping on the hot path). The structured API —
-``span()`` as a context manager, or a manual handle whose ``end()``
-must run on every path (enforced by the ``span-leak`` lint rule) — is
-for region-shaped sites (stream regions, rank steps).
+Rank attribution is the ``rank=`` argument of the fabric's rank-step
+span; inside it the thread's rank is also what the residual ledger
+charges.
 
-Rank attribution: fabric rank threads come from a ``ThreadPoolExecutor``
-that re-assigns threads to ranks arbitrarily per step, so thread
-identity is NOT rank identity. ``rank_scope(rank)`` pushes the rank
-onto a thread-local stack for the duration of a rank's step; every
-event emitted inside carries that rank as its Perfetto lane (``tid``).
-Span nesting state is thread-local too, so concurrent rank threads
-never interleave each other's stacks.
-
-The tracer owns the trial's :class:`~repro.obs.residuals.ResidualLedger`
-(``tracer.residuals``): ``hop()`` records a modeled-vs-measured pair
-AND emits the hop's span in one call, and ``on_wait`` feeds the
+The residual ledger (:class:`~repro.obs.residuals.ResidualLedger`) is
+opt-in state, on under ``REPRO_TRACE=1`` or :func:`install`. A span marked
+with :meth:`Span.hop` is a priced hop: at its end the ledger records the
+modeled price against the span's measured time, and the span carries
+``hop``, ``modeled_s`` and ``measured_s``. :func:`on_wait` feeds the
 serialization-stall detector from ``Request.wait``.
 """
 
 from __future__ import annotations
 
-import json
 import os
 import threading
 import time
-from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.residuals import ResidualLedger
 
-#: default ring capacity — a smoke-scale fabric trial is ~10k events
-DEFAULT_CAPACITY = 65536
+_tls = threading.local()
 
-#: Perfetto lane for events outside any rank scope (driver/router
-#: threads get DRIVER_TID + a per-thread index)
-DRIVER_TID = 1000
+
+def current_rank() -> Optional[int]:
+    """The rank whose step this thread is running (``rank=`` of the
+    innermost enclosing span that set one), else None."""
+    return getattr(_tls, "rank", None)
 
 
 class Span:
-    """Handle for an open span. Context-manager use is exception-safe
-    by construction; manual use must call :meth:`end` on every path
-    (the ``span-leak`` lint rule checks exactly this)."""
+    """A host span: a ``TraceAnnotation`` that also times itself. Use it
+    as a context manager; a handle entered by hand must reach
+    :meth:`end` (the ``span-leak`` lint rule checks that)."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "t0", "tid", "parent",
-                 "_open")
+    __slots__ = ("name", "t0", "seconds", "_ann", "_phases", "_hop",
+                 "_rank", "_outer_rank")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Dict[str, Any], t0: float, tid: int,
-                 parent: Optional[str]):
-        self._tracer = tracer
+    def __init__(self, name: str, phases: Optional[Dict[str, float]] = None,
+                 **args):
         self.name = name
-        self.cat = cat
-        self.args = args
-        self.t0 = t0
-        self.tid = tid
-        self.parent = parent
-        self._open = True
-
-    def end(self) -> None:
-        if self._open:
-            self._open = False
-            self._tracer._end_span(self)
+        self.seconds = 0.0
+        self._ann = TraceAnnotation(name, **args)
+        self._phases = phases
+        self._hop = None
+        self._rank = args.get("rank")
 
     def __enter__(self) -> "Span":
+        if self._rank is not None:
+            self._outer_rank = current_rank()
+            _tls.rank = self._rank
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
+
+    def set(self, **args) -> None:
+        """Arguments known only once the span's work has run."""
+        self._ann.set_metadata(**args)
+
+    def hop(self, kind: str, modeled_s: float) -> None:
+        """Price this span as a hop of ``kind`` (the §3.2 model's cost)."""
+        self._hop = (kind, float(modeled_s))
 
     def __exit__(self, *exc) -> bool:
-        self.end()
+        self.seconds = time.perf_counter() - self.t0
+        if self._hop is not None and (not exc or exc[0] is None):
+            kind, modeled = self._hop
+            led = _LEDGER
+            if led is not None:
+                led.record(kind, modeled, self.seconds, rank=current_rank())
+            self._ann.set_metadata(hop=kind, modeled_s=modeled,
+                                   measured_s=self.seconds)
+        self._ann.__exit__(*exc)
+        if self._rank is not None:
+            _tls.rank = self._outer_rank
+        if self._phases is not None:
+            ph = self._phases
+            ph[self.name] = ph.get(self.name, 0.0) + self.seconds
         return False
 
-
-class Tracer:
-    """Ring-buffered span recorder with per-thread nesting state."""
-
-    def __init__(self, capacity: int = DEFAULT_CAPACITY):
-        if capacity < 1:
-            raise ValueError("ring capacity must be >= 1")
-        self.capacity = int(capacity)
-        self._events: deque = deque()
-        self._lock = threading.Lock()
-        self._tls = threading.local()
-        self._t0 = time.perf_counter()
-        self.dropped = 0          # events evicted oldest-first
-        self.unbalanced = 0       # manual end() out of LIFO order
-        self.residuals = ResidualLedger()
-        # tid -> lane name for the Perfetto thread_name metadata
-        self._lane_names: Dict[int, str] = {}
-        self._next_driver_lane = DRIVER_TID
-
-    # -- thread-local context ----------------------------------------------
-    def _stack(self) -> List[Span]:
-        st = getattr(self._tls, "stack", None)
-        if st is None:
-            st = self._tls.stack = []
-        return st
-
-    def _ranks(self) -> List[int]:
-        rk = getattr(self._tls, "ranks", None)
-        if rk is None:
-            rk = self._tls.ranks = []
-        return rk
-
-    def current_rank(self) -> Optional[int]:
-        rk = self._ranks()
-        return rk[-1] if rk else None
-
-    def rank_scope(self, rank: int):
-        """Attribute everything emitted on this thread to ``rank`` until
-        exit — the fabric worker wraps each rank step in one of these
-        (pool threads are reassigned to ranks arbitrarily, so thread
-        identity cannot stand in for rank identity)."""
-        return _RankScope(self, int(rank))
-
-    def set_runnable(self, n: int) -> None:
-        """Thread-local runnable-work hint for the stall detector: the
-        count of live rows + queued requests this rank could be
-        advancing right now. Set by the engine at each micro-step."""
-        self._tls.runnable = int(n)
-
-    def _runnable(self) -> int:
-        return getattr(self._tls, "runnable", 0)
-
-    def _tid(self) -> int:
-        """Perfetto lane: the innermost rank scope, else a stable
-        per-thread driver lane."""
-        rank = self.current_rank()
-        if rank is not None:
-            with self._lock:
-                self._lane_names.setdefault(rank, f"rank {rank}")
-            return rank
-        lane = getattr(self._tls, "lane", None)
-        if lane is None:
-            with self._lock:
-                lane = self._next_driver_lane
-                self._next_driver_lane += 1
-                self._lane_names[lane] = threading.current_thread().name
-            self._tls.lane = lane
-        return lane
-
-    # -- recording ---------------------------------------------------------
-    def _us(self, t: float) -> float:
-        return (t - self._t0) * 1e6
-
-    def _emit(self, ev: dict) -> None:
-        with self._lock:
-            if len(self._events) >= self.capacity:
-                self._events.popleft()    # ring: oldest-first eviction
-                self.dropped += 1
-            self._events.append(ev)
-
-    def span(self, name: str, cat: str = "", **args) -> Span:
-        """Open a span on this thread's stack. Use as a context manager
-        (``with tr.span(...):``) or keep the handle and ``end()`` it on
-        every path — the span-leak lint rule enforces the latter."""
-        stack = self._stack()
-        parent = stack[-1].name if stack else None
-        sp = Span(self, name, cat, args, time.perf_counter(), self._tid(),
-                  parent)
-        stack.append(sp)
-        return sp
-
-    def _end_span(self, sp: Span) -> None:
-        t1 = time.perf_counter()
-        stack = self._stack()
-        if stack and stack[-1] is sp:
-            stack.pop()
-        else:
-            # manual-API misuse (end out of LIFO order, or a cross-
-            # thread end): recover by removing it wherever it sits
-            try:
-                stack.remove(sp)
-            except ValueError:
-                pass
-            self.unbalanced += 1
-        args = dict(sp.args)
-        if sp.parent is not None:
-            args["parent"] = sp.parent
-        self._emit({"name": sp.name, "cat": sp.cat or "span", "ph": "X",
-                    "ts": self._us(sp.t0), "dur": (t1 - sp.t0) * 1e6,
-                    "pid": 0, "tid": sp.tid, "args": args})
-
-    def complete(self, name: str, t0: float, t1: float, cat: str = "",
-                 **args) -> None:
-        """Hot-path pre-timed event: the caller read ``perf_counter``
-        around the region; no stack bookkeeping, one emit."""
-        stack = self._stack()
-        if stack:
-            args["parent"] = stack[-1].name
-        self._emit({"name": name, "cat": cat or "span", "ph": "X",
-                    "ts": self._us(t0), "dur": (t1 - t0) * 1e6,
-                    "pid": 0, "tid": self._tid(), "args": args})
-
-    def instant(self, name: str, cat: str = "", **args) -> None:
-        """Point event (scheduler admit/defer decisions)."""
-        self._emit({"name": name, "cat": cat or "event", "ph": "i",
-                    "ts": self._us(time.perf_counter()), "s": "t",
-                    "pid": 0, "tid": self._tid(), "args": args})
-
-    def counter(self, name: str, **values) -> None:
-        """Perfetto counter track (block-pool occupancy, queue depth)."""
-        self._emit({"name": name, "cat": "counter", "ph": "C",
-                    "ts": self._us(time.perf_counter()),
-                    "pid": 0, "tid": self._tid(), "args": values})
-
-    def hop(self, kind: str, modeled_s: float, t0: float, t1: float,
-            **args) -> None:
-        """A priced hop: record the modeled-vs-measured pair in the
-        residual ledger AND emit the hop's span in one call — every
-        dispatch/migrate/admission hop in the trace carries its
-        residual in ``args``."""
-        measured = t1 - t0
-        rank = self.current_rank()
-        self.residuals.record(kind, modeled_s, measured, rank=rank)
-        args["modeled_s"] = float(modeled_s)
-        args["measured_s"] = float(measured)
-        if modeled_s > 0:
-            args["residual_ratio"] = measured / modeled_s
-        self.complete(f"hop:{kind}", t0, t1, cat="residual", **args)
-
-    def on_wait(self, op: str, t0: float, t1: float) -> None:
-        """Comm completion point (``Request.wait``): emit the wait span
-        and, when this thread's runnable hint is set, charge the blocked
-        time to the serialization-stall detector."""
-        runnable = self._runnable()
-        if runnable > 0:
-            self.residuals.stall(t1 - t0, rank=self.current_rank())
-        self.complete(f"wait:{op}", t0, t1, cat="comm", runnable=runnable)
-
-    # -- trial lifecycle ---------------------------------------------------
-    def flush_trial(self) -> None:
-        """Trial boundary (post-warm-up reset / fabric close): drop the
-        residual pairs and stall accumulators so warm-up measurements —
-        compile-dominated, hence wildly off-model — never aggregate into
-        a measured trial's report. The event ring is kept: the timeline
-        showing warm-up next to the trial is a feature."""
-        self.residuals.reset()
-
-    # -- export ------------------------------------------------------------
-    def events(self) -> List[dict]:
-        with self._lock:
-            return list(self._events)
-
-    @property
-    def n_events(self) -> int:
-        with self._lock:
-            return len(self._events)
-
-    def chrome_trace(self) -> dict:
-        """Chrome ``trace_event`` JSON object: per-lane thread_name
-        metadata (rank lanes sort first) + the ring's events by time."""
-        with self._lock:
-            events = list(self._events)
-            lanes = dict(self._lane_names)
-        meta: List[dict] = [
-            {"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
-             "args": {"name": "repro-serve"}},
-        ]
-        for tid, lane_name in sorted(lanes.items()):
-            meta.append({"name": "thread_name", "ph": "M", "pid": 0,
-                         "tid": tid, "args": {"name": lane_name}})
-            meta.append({"name": "thread_sort_index", "ph": "M", "pid": 0,
-                         "tid": tid, "args": {"sort_index": tid}})
-        events.sort(key=lambda e: e["ts"])
-        return {"displayTimeUnit": "ms",
-                "traceEvents": meta + events,
-                "metadata": {"dropped_events": self.dropped}}
-
-    def export_json(self) -> str:
-        return json.dumps(self.chrome_trace())
-
-    def write_chrome(self, path: str) -> None:
-        with open(path, "w") as f:
-            f.write(self.export_json())
+    def end(self) -> None:
+        self.__exit__(None, None, None)
 
 
-class _RankScope:
-    __slots__ = ("_tracer", "_rank")
+span = Span
 
-    def __init__(self, tracer: Tracer, rank: int):
-        self._tracer = tracer
-        self._rank = rank
 
-    def __enter__(self):
-        self._tracer._ranks().append(self._rank)
-        return self
+def set_runnable(n: int) -> None:
+    """Thread-local runnable-work hint for the stall detector: the live
+    rows and queued requests this thread could be advancing now."""
+    _tls.runnable = int(n)
 
-    def __exit__(self, *exc):
-        rk = self._tracer._ranks()
-        if rk and rk[-1] == self._rank:
-            rk.pop()
-        return False
+
+def on_wait(seconds: float) -> None:
+    """A comm completion blocked this thread for ``seconds``: charged to
+    the stall detector when the ledger is on and the thread had runnable
+    work."""
+    led = _LEDGER
+    if led is not None and getattr(_tls, "runnable", 0) > 0:
+        led.stall(seconds, rank=current_rank())
 
 
 # ---------------------------------------------------------------------------
-# Global activation — the sanitizer's exact pattern (DESIGN.md §11):
-# instrumented sites read one module global and None-check it; when
-# nothing is installed the telemetry is compiled out of the hot path.
+# The ledger's activation — the sanitizer's pattern (DESIGN.md §11): one
+# module global, None when off.
 # ---------------------------------------------------------------------------
 
-_TRACER: Optional[Tracer] = None
+_LEDGER: Optional[ResidualLedger] = None
 
 
-def active() -> Optional[Tracer]:
-    return _TRACER
+def ledger() -> Optional[ResidualLedger]:
+    return _LEDGER
 
 
-def install(capacity: int = DEFAULT_CAPACITY) -> Tracer:
-    global _TRACER
-    _TRACER = Tracer(capacity=capacity)
-    return _TRACER
+def install() -> ResidualLedger:
+    global _LEDGER
+    _LEDGER = ResidualLedger()
+    return _LEDGER
 
 
 def uninstall() -> None:
-    global _TRACER
-    _TRACER = None
+    global _LEDGER
+    _LEDGER = None
 
 
 def flush_trial() -> None:
-    """Module-level trial flush for reset/close hooks: a no-op when
-    tracing is off, a residual-ledger reset when on."""
-    tr = _TRACER
-    if tr is not None:
-        tr.flush_trial()
+    """Trial boundary (post-warm-up reset / fabric close): drop the
+    residual pairs and stall time so compile-heavy warm-up never counts
+    in a measured trial. A no-op when the ledger is off."""
+    led = _LEDGER
+    if led is not None:
+        led.reset()
 
 
-def _truthy(v: str) -> bool:
-    return v.strip().lower() in ("1", "true", "yes", "on")
-
-
-if _truthy(os.environ.get("REPRO_TRACE", "")):
-    install(capacity=int(os.environ.get("REPRO_TRACE_CAPACITY",
-                                        str(DEFAULT_CAPACITY))))
+if os.environ.get("REPRO_TRACE", "").strip().lower() in ("1", "true", "yes",
+                                                         "on"):
+    install()

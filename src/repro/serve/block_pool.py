@@ -43,7 +43,6 @@ import numpy as np
 
 from repro.analysis.sanitizer import active as _san_active
 from repro.obs.metrics import active as _reg_active
-from repro.obs.trace import active as _tr_active
 from repro.serve.kv_cache import LeaseLeakError, LeaseLeakWarning, SlotError
 
 
@@ -158,15 +157,10 @@ class BlockPool:
         self._observe_occupancy()
 
     def _observe_occupancy(self) -> None:
-        """Telemetry (DESIGN.md §15): block-pool occupancy as a Perfetto
-        counter track + a registry gauge, sampled at lease transitions
-        (per request admission/finish, not per token — alloc/free are
-        the only places occupancy moves)."""
-        tr = _tr_active()
-        if tr is not None:
-            free = len(self._free)
-            tr.counter("block_pool", free=free,
-                       live=self.num_blocks - free)
+        """Telemetry (DESIGN.md §15): block-pool occupancy as registry
+        gauges, sampled at lease transitions (per request admission/
+        finish, not per token — alloc/free are the only places occupancy
+        moves). The engine's ``serve.step`` span carries ``blocks_free``."""
         reg = _reg_active()
         if reg is not None:
             reg.gauge("block_pool.free_blocks").set(len(self._free))

@@ -63,27 +63,29 @@ Threadcomm integration:
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Deque, List, Optional
+from typing import Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro.core import protocol
-# telemetry (REPRO_TRACE=1, DESIGN.md §15): micro-step spans, admission
-# residual hops, trial flush — one global read + None check when off
+# telemetry (DESIGN.md §15): one profiler span per step phase, priced
+# hops for the residual ledger, the trial flush
 from repro.obs import flush_trial as _obs_flush_trial
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import active as _tr_active
+from repro.obs import trace as obs_trace
+from repro.obs.trace import span
 from repro.serve.block_pool import PagedKVCache
 from repro.serve.kv_cache import SlotError, SlotKVCache
 from repro.serve.prefix_cache import PrefixCache
 from repro.serve.scheduler import CellQueueScheduler, ServeRequest
 
 
+@jax.named_scope("sample")
 def _sample_rows(logits, keys, temps):
     """Per-row sampling: greedy when temp <= 0, else temperature
     categorical with that row's own PRNG key. logits (B, Vp)."""
@@ -489,6 +491,9 @@ class ContinuousEngine:
         #: partially-deposited requests, FIFO; each micro-step serves the
         #: first ``max_prefill_per_step`` of them with one fused dispatch
         self._prefilling: Deque[_PrefillJob] = deque()
+        #: wall seconds of each ``serve.*`` phase of the latest step,
+        #: kept whether or not a profiler runs
+        self.last_step_phases: Dict[str, float] = {}
         #: role="prefill": prefill-complete requests awaiting migration
         #: (their rows/blocks stay leased until release_handoff)
         self.ready_handoffs: List[KVHandoff] = []
@@ -547,6 +552,7 @@ class ContinuousEngine:
         })
 
     @staticmethod
+    @jax.named_scope("sample")
     def _advance_state(state, logits):
         """Shared decode tail of the slot and paged dispatches: sample
         each row with its own key chain and advance (tok, pos, keys).
@@ -612,6 +618,7 @@ class ContinuousEngine:
         }
 
     @staticmethod
+    @jax.named_scope("sample")
     def _install_finalized_rows(state, logits, rows, fin_pos, keys, temps,
                                 drop_row):
         """Shared chunked-prefill tail of the slot and paged dispatches:
@@ -812,88 +819,80 @@ class ContinuousEngine:
         ``max_prefill_per_step`` requests (one chunk-row each, fused into
         a single dispatch on the chunked path), then advance every
         decoding slot by one token. Returns the requests that finished
-        this step."""
-        tr = _tr_active()
-        if tr is not None:
-            # runnable-work hint for the serialization-stall detector:
-            # rows + queued requests this engine could be advancing
-            tr.set_runnable(self.kv.num_live + self.scheduler.num_waiting)
+        this step.
+
+        Each phase runs in a :func:`~repro.obs.trace.span` (``serve.*``)
+        whose wall time also lands in :attr:`last_step_phases`."""
+        phases: Dict[str, float] = {}
+        self.last_step_phases = phases
+        # runnable-work hint for the serialization-stall detector: rows +
+        # queued requests this engine could be advancing
+        obs_trace.set_runnable(self.kv.num_live + self.scheduler.num_waiting)
         finished: List[ServeRequest] = []
-        if self.prefill_chunk:
-            # admission keeps at most max_prefill_per_step prompts
-            # in flight; each gets one chunk per micro-step, so decode
-            # stalls are bounded by one chunk of prefill compute
-            budget = min(self.kv.num_free,
-                         self.max_prefill_per_step - len(self._prefilling))
-            # paged: the second admission gate is the block pool — a
-            # request is held back (head-of-line) until its whole token
-            # budget (prompt + max_new) fits in free blocks. Admit one
-            # request at a time so each lease is debited from the free
-            # pool before the next candidate is gated. With the prefix
-            # cache, only the miss tail needs fresh blocks: the gate
-            # prices the hit so shared-prefix bursts admit earlier.
-            can = ((lambda r: self.kv.can_admit(
-                self._token_budget(r),
-                hit=(self._prefix_lookup(r) if self.prefix_cache
-                     is not None else None)))
-                if self.kv_layout == "paged" else None)
-            while budget > 0:
-                admitted = self.scheduler.admit(now, 1, can_admit=can)
-                if not admitted:
-                    break
-                req = admitted[0]
-                if tr is None:
-                    self._begin_prefill(req)
-                else:
-                    # the admission hop's wall-clock twin of the §3.2
-                    # price stamped on the request (repriced to the
-                    # prefix-hit model when the radix cache served it)
-                    t0 = time.perf_counter()
-                    self._begin_prefill(req)
-                    tr.hop("prefix_hit" if req.prefix_hit_tokens > 0
-                           else "admission", req.admit_cost_s, t0,
-                           time.perf_counter(), rid=req.rid)
-                budget -= 1
-            if self._prefilling:
-                if tr is None:
-                    finished.extend(self._prefill_chunk_step(now))
-                else:
-                    nj = min(len(self._prefilling),
-                             self.max_prefill_per_step)
-                    t0 = time.perf_counter()
-                    finished.extend(self._prefill_chunk_step(now))
-                    tr.complete("prefill_chunk", t0, time.perf_counter(),
-                                cat="engine", jobs=nj)
-        else:
-            n_admit = min(self.kv.num_free, self.max_prefill_per_step)
-            for req in self.scheduler.admit(now, n_admit):
-                if tr is None:
-                    done = self._admit(req, now)
-                else:
-                    t0 = time.perf_counter()
-                    done = self._admit(req, now)
-                    tr.hop("admission", req.admit_cost_s, t0,
-                           time.perf_counter(), rid=req.rid)
-                if done is not None:
-                    finished.append(done)
-        if self.num_decoding:
-            if tr is None:
-                finished.extend(self._spec_micro_step(now)
-                                if self.speculate
-                                else self._decode_micro_step(now))
-            elif self.speculate:
-                t0 = time.perf_counter()
-                finished.extend(self._spec_micro_step(now))
-                tr.complete("spec_round", t0, time.perf_counter(),
-                            cat="engine")
+        with span("serve.step", phases, rows=self.kv.num_live,
+                  prefilling=len(self._prefilling),
+                  decoding=self.num_decoding,
+                  blocks_free=(self.kv.num_free_blocks
+                               if self.kv_layout == "paged"
+                               else self.kv.num_free)) as sp:
+            deferrals = self.scheduler.n_block_deferrals
+            if self.prefill_chunk:
+                self._admit_chunked(now, phases)
+                if self._prefilling:
+                    finished.extend(self._prefill_chunk_step(now, phases))
             else:
-                rows = self.num_decoding
-                t0 = time.perf_counter()
-                finished.extend(self._decode_micro_step(now))
-                tr.complete("decode", t0, time.perf_counter(),
-                            cat="engine", rows=rows)
-        self._account()
+                n_admit = min(self.kv.num_free, self.max_prefill_per_step)
+                for req in self.scheduler.admit(now, n_admit):
+                    with span("serve.prefill", phases, rid=req.rid,
+                              prompt_len=req.prompt_len) as adm:
+                        done = self._admit(req, now)
+                        adm.hop("admission", req.admit_cost_s)
+                    if done is not None:
+                        finished.append(done)
+            if self.num_decoding:
+                finished.extend(self._spec_micro_step(now, phases)
+                                if self.speculate
+                                else self._decode_micro_step(now, phases))
+            self._account()
+            sp.set(deferred=self.scheduler.n_block_deferrals - deferrals,
+                   finished=len(finished))
         return finished
+
+    def _admit_chunked(self, now: float, phases: Dict[str, float]) -> None:
+        """Admit queued requests into the prefilling state: at most
+        ``max_prefill_per_step`` prompts are in flight, and each gets one
+        chunk per micro-step, so decode stalls are bounded by one chunk of
+        prefill compute."""
+        budget = min(self.kv.num_free,
+                     self.max_prefill_per_step - len(self._prefilling))
+        # paged: the second admission gate is the block pool — a request
+        # is held back (head-of-line) until its whole token budget
+        # (prompt + max_new) fits in free blocks. Admit one request at a
+        # time so each lease is debited from the free pool before the
+        # next candidate is gated. With the prefix cache, only the miss
+        # tail needs fresh blocks: the gate prices the hit so
+        # shared-prefix bursts admit earlier.
+        can = ((lambda r: self.kv.can_admit(
+            self._token_budget(r),
+            hit=(self._prefix_lookup(r) if self.prefix_cache
+                 is not None else None)))
+            if self.kv_layout == "paged" else None)
+        while budget > 0:
+            admitted = self.scheduler.admit(now, 1, can_admit=can)
+            if not admitted:
+                break
+            req = admitted[0]
+            with span("serve.admit", phases, rid=req.rid,
+                      prompt_len=req.prompt_len, protocol=req.protocol,
+                      waited_s=req.admit_time - req.arrival) as sp:
+                self._begin_prefill(req)
+                # the admission hop's wall-clock twin of the §3.2 price
+                # stamped on the request (repriced to the prefix-hit
+                # model when the radix cache served it)
+                sp.hop("prefix_hit" if req.prefix_hit_tokens > 0
+                       else "admission", req.admit_cost_s)
+                sp.set(prefix_hit_tokens=req.prefix_hit_tokens)
+            budget -= 1
 
     def _token_budget(self, req: ServeRequest) -> int:
         """Token capacity a request leases at admission: the prompt plus
@@ -944,6 +943,42 @@ class ContinuousEngine:
         """Thin alias — canonical schema:
         :func:`repro.obs.metrics.engine_spec_stats`."""
         return obs_metrics.engine_spec_stats(self)
+
+    def program_text(self) -> Dict[str, str]:
+        """Optimized HLO of the paged decode and chunk programs at this
+        engine's shapes, keyed by jitted name. A profile names each device
+        op by its HLO instruction; the instruction's ``op_name`` metadata
+        carries the model's named scopes (``attention``, ``mlp``,
+        ``lm_head``, ``sample``...).
+
+        Traced and compiled anew, past JAX's caches: the persistent cache
+        keys a program without its metadata, so the executable the engine
+        runs may carry the op names of a build that differs only in its
+        scopes. The instructions' names are the same."""
+        if self.kv_layout != "paged" or not self.prefill_chunk:
+            raise ValueError("program_text covers the paged chunked engine")
+        P, C = self.max_prefill_per_step, self.prefill_chunk
+        row = jnp.zeros((P,), jnp.int32)
+        programs = {
+            "_chunk_traced": (
+                self._chunk_impl_paged(self.model, self.kv.num_slots),
+                (jnp.zeros((P, C), jnp.int32), row,
+                 jnp.zeros((P, self.kv.max_blocks_per_req), jnp.int32), row,
+                 row, row, jnp.zeros((P, 2), jnp.uint32),
+                 jnp.zeros((P,), jnp.float32))),
+            "_decode_traced": (self._decode_impl_paged(self.model),
+                               (self.kv.tables_device(),)),
+        }
+        cached = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            return {name: jax.jit(fn, donate_argnums=(1, 2)).lower(
+                self.params, self.kv.buffers, self._state, *rest
+            ).compile().as_text() for name, (fn, rest) in programs.items()}
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cached)
+            compilation_cache.reset_cache()
 
     # -- chunked prompt deposit (rendezvous-style streaming) ---------------
     def _begin_prefill(self, req: ServeRequest) -> None:
@@ -1032,7 +1067,8 @@ class ContinuousEngine:
                 req, resident, cow_blocks=int(hit.cow_src is not None))
         return slot, resident
 
-    def _prefill_chunk_step(self, now: float) -> List[ServeRequest]:
+    def _prefill_chunk_step(self, now: float,
+                            phases: Dict[str, float]) -> List[ServeRequest]:
         """One fused dispatch: the next chunk of up to
         ``max_prefill_per_step`` prefilling requests, batched row-wise at
         fixed shapes (shorter tails padded + masked, absent rows aimed at
@@ -1040,76 +1076,75 @@ class ContinuousEngine:
         P, C = self.max_prefill_per_step, self.prefill_chunk
         S = self.kv.num_slots
         jobs = list(self._prefilling)[:P]
-        tok = np.zeros((P, C), np.int32)
-        slots = np.full((P,), S, np.int32)         # S = drop row
-        pos0 = np.zeros((P,), np.int32)
-        n_valid = np.zeros((P,), np.int32)
-        fin_pos = np.full((P,), -1, np.int32)
-        temps = np.zeros((P,), np.float32)
-        keys = np.zeros((P, 2), np.uint32)
-        for i, job in enumerate(jobs):
-            n = min(C, len(job.tokens) - job.off)
-            tok[i, :n] = job.tokens[job.off:job.off + n]
-            slots[i] = job.slot
-            pos0[i] = job.off
-            n_valid[i] = n
-            if job.off + n >= len(job.tokens):
-                fin_pos[i] = len(job.tokens)       # next decode position
-            temps[i] = job.req.temperature
-            keys[i] = np.asarray(job.key, np.uint32)
-            job.req.prefill_chunks += 1
-        if self.kv_layout == "paged":
-            # rows double as state-install targets (S = drop row); the
-            # block tables are the write indirection — padding rows carry
-            # all -1 tables, so every pool write drops
-            buf, state, tok0 = self._chunk(
-                self.params, self.kv.buffers, self._state, jnp.asarray(tok),
-                jnp.asarray(slots), jnp.asarray(self.kv.table_rows(slots)),
-                jnp.asarray(pos0), jnp.asarray(n_valid),
-                jnp.asarray(fin_pos), jnp.asarray(keys), jnp.asarray(temps))
-        else:
-            buf, state, tok0 = self._chunk(
-                self.params, self.kv.buffers, self._state, jnp.asarray(tok),
-                jnp.asarray(slots), jnp.asarray(pos0), jnp.asarray(n_valid),
-                jnp.asarray(fin_pos), jnp.asarray(keys), jnp.asarray(temps))
-        self.kv.swap_buffers(self._prefill_stream.ordered(buf))
-        self._state = state
-        if self.kv_layout == "paged" and self.speculate:
-            # mirror the prompt chunk into the drafter's pool: same
-            # tokens/offsets, the drafter's own tables (its rows were
-            # leased in lockstep at _begin_prefill)
-            dbuf = self._draft_chunk(
-                self.draft_params, self.draft_kv.buffers,
-                jnp.asarray(tok),
-                jnp.asarray(self.draft_kv.table_rows(slots)),
-                jnp.asarray(slots), jnp.asarray(pos0),
-                jnp.asarray(n_valid))
-            self.draft_kv.swap_buffers(self._draft_stream.ordered(dbuf))
+        with span("serve.chunk.build", phases):
+            tok = np.zeros((P, C), np.int32)
+            slots = np.full((P,), S, np.int32)         # S = drop row
+            pos0 = np.zeros((P,), np.int32)
+            n_valid = np.zeros((P,), np.int32)
+            fin_pos = np.full((P,), -1, np.int32)
+            temps = np.zeros((P,), np.float32)
+            keys = np.zeros((P, 2), np.uint32)
+            for i, job in enumerate(jobs):
+                n = min(C, len(job.tokens) - job.off)
+                tok[i, :n] = job.tokens[job.off:job.off + n]
+                slots[i] = job.slot
+                pos0[i] = job.off
+                n_valid[i] = n
+                if job.off + n >= len(job.tokens):
+                    fin_pos[i] = len(job.tokens)       # next decode position
+                temps[i] = job.req.temperature
+                keys[i] = np.asarray(job.key, np.uint32)
+                job.req.prefill_chunks += 1
+            host = [tok, slots, pos0, n_valid, fin_pos, keys, temps]
+            if self.kv_layout == "paged":
+                # rows double as state-install targets (S = drop row); the
+                # block tables are the write indirection — padding rows
+                # carry all -1 tables, so every pool write drops
+                host.insert(2, self.kv.table_rows(slots))
+            args = [jnp.asarray(a) for a in host]
+        with span("serve.chunk.dispatch", phases):
+            buf, state, tok0 = self._chunk(self.params, self.kv.buffers,
+                                           self._state, *args)
+            self.kv.swap_buffers(self._prefill_stream.ordered(buf))
+            self._state = state
+            if self.kv_layout == "paged" and self.speculate:
+                # mirror the prompt chunk into the drafter's pool: same
+                # tokens/offsets, the drafter's own tables (its rows were
+                # leased in lockstep at _begin_prefill)
+                tok_d, slots_d, _, pos0_d, n_valid_d = args[:5]
+                dbuf = self._draft_chunk(
+                    self.draft_params, self.draft_kv.buffers, tok_d,
+                    jnp.asarray(self.draft_kv.table_rows(slots)), slots_d,
+                    pos0_d, n_valid_d)
+                self.draft_kv.swap_buffers(self._draft_stream.ordered(dbuf))
 
-        finished: List[ServeRequest] = []
         tok0_np = None
-        for i, job in enumerate(jobs):
-            job.off += int(n_valid[i])
-            self.kv.advance(job.slot, int(n_valid[i]))  # pages appended
-            if fin_pos[i] < 0:
-                continue
-            if self.speculate:
-                # drafter now holds the full prompt; emitted tokens are
-                # what each round's resync dispatch will feed it
-                self._draft_len[job.slot] = len(job.tokens)
-            if tok0_np is None:       # host sync only when a prompt completes
+        if (fin_pos >= 0).any():
+            with span("serve.chunk.sync", phases):
+                # host sync only when a prompt completes
                 tok0_np = np.asarray(tok0)
-            self._prefilling.remove(job)
-            if self.prefix_cache is not None:
-                # index the finished prompt's full blocks now — before
-                # the request can finish immediately (EOS first token)
-                # and free them down to parked
-                self.prefix_cache.insert(job.tokens,
-                                         self.kv.blocks_of(job.slot))
-            done = self._install_first_token(job.slot, job.req,
-                                             int(tok0_np[i]), now)
-            if done is not None:
-                finished.append(done)
+        finished: List[ServeRequest] = []
+        with span("serve.chunk.retire", phases):
+            for i, job in enumerate(jobs):
+                job.off += int(n_valid[i])
+                self.kv.advance(job.slot, int(n_valid[i]))  # pages appended
+                if fin_pos[i] < 0:
+                    continue
+                if self.speculate:
+                    # drafter now holds the full prompt; emitted tokens
+                    # are what each round's resync dispatch will feed it
+                    self._draft_len[job.slot] = len(job.tokens)
+                self._prefilling.remove(job)
+                if self.prefix_cache is not None:
+                    # index the finished prompt's full blocks now —
+                    # before the request can finish immediately (EOS
+                    # first token) and free them down to parked
+                    self.prefix_cache.insert(job.tokens,
+                                             self.kv.blocks_of(job.slot))
+                done = self._install_first_token(job.slot, job.req,
+                                                 int(tok0_np[i]), now)
+                if done is not None:
+                    finished.append(done)
         return finished
 
     def _admit(self, req: ServeRequest, now: float) -> Optional[ServeRequest]:
@@ -1161,36 +1196,41 @@ class ContinuousEngine:
         self._slot_out[slot] = out
         return None
 
-    def _decode_micro_step(self, now: float) -> List[ServeRequest]:
-        state = self._decode_stream.ordered(self._state)
-        if self.kv_layout == "paged":
-            nxt, buf, state = self._decode(self.params, self.kv.buffers,
-                                           state, self.kv.tables_device())
-        else:
-            nxt, buf, state = self._decode(self.params, self.kv.buffers,
-                                           state)
-        self.kv.swap_buffers(buf)
-        self._state = state
-        nxt_np = np.asarray(nxt)        # the one host sync per micro-step
+    def _decode_micro_step(self, now: float,
+                           phases: Dict[str, float]) -> List[ServeRequest]:
+        with span("serve.decode.dispatch", phases):
+            state = self._decode_stream.ordered(self._state)
+            if self.kv_layout == "paged":
+                nxt, buf, state = self._decode(self.params, self.kv.buffers,
+                                               state, self.kv.tables_device())
+            else:
+                nxt, buf, state = self._decode(self.params, self.kv.buffers,
+                                               state)
+            self.kv.swap_buffers(buf)
+            self._state = state
+        with span("serve.decode.sync", phases):
+            nxt_np = np.asarray(nxt)    # the one host sync per micro-step
 
         finished: List[ServeRequest] = []
-        for slot in self.kv.live_slots:
-            req = self._slot_req[slot]
-            if req is None:        # slot still mid-prefill: nothing to read
-                continue
-            t = int(nxt_np[slot])
-            out = self._slot_out[slot]
-            out[req.generated] = t
-            req.generated += 1
-            self.kv.advance(slot)
-            if (0 <= self.eos_id == t) \
-                    or req.generated >= req.max_new_tokens:
-                finished.append(self._finish(slot, req, out, now))
-                self._slot_req[slot] = None
-                self._slot_out[slot] = None
+        with span("serve.decode.retire", phases):
+            for slot in self.kv.live_slots:
+                req = self._slot_req[slot]
+                if req is None:    # slot still mid-prefill: nothing to read
+                    continue
+                t = int(nxt_np[slot])
+                out = self._slot_out[slot]
+                out[req.generated] = t
+                req.generated += 1
+                self.kv.advance(slot)
+                if (0 <= self.eos_id == t) \
+                        or req.generated >= req.max_new_tokens:
+                    finished.append(self._finish(slot, req, out, now))
+                    self._slot_req[slot] = None
+                    self._slot_out[slot] = None
         return finished
 
-    def _spec_micro_step(self, now: float) -> List[ServeRequest]:
+    def _spec_micro_step(self, now: float,
+                         phases: Dict[str, float]) -> List[ServeRequest]:
         """Spec-mode decode micro-step: ONE fused draft–verify round over
         every decoding row (``_spec_round_impl``) replaces up to ``k+1``
         single-token dispatches. The host builds the round's inputs from
@@ -1229,25 +1269,21 @@ class ContinuousEngine:
             tpos[slot] = canon - 1
             n_draft[slot] = min(k, req.max_new_tokens - g - 1)
             live.append(slot)
-        tr = _tr_active()
-        t_disp = time.perf_counter() if tr is not None else 0.0
-        greedy, n_emit, buf, dbuf = self._spec_round(
-            self.params, self.draft_params, self.kv.buffers,
-            self.draft_kv.buffers, jnp.asarray(cur), jnp.asarray(prev),
-            jnp.asarray(u), jnp.asarray(sync_pos), jnp.asarray(tpos),
-            jnp.asarray(n_draft), self.kv.tables_device(),
-            self.draft_kv.tables_device())
-        self.kv.swap_buffers(self._verify_stream.ordered(buf))
-        self.draft_kv.swap_buffers(self._draft_stream.ordered(dbuf))
-        greedy_np = np.asarray(greedy)     # the one host sync per round
-        n_emit_np = np.asarray(n_emit)
-
         cost = protocol.speculative_verify_latency(k)
-        if tr is not None:
+        with span("serve.spec", phases, rows=len(live), k=k) as sp:
+            greedy, n_emit, buf, dbuf = self._spec_round(
+                self.params, self.draft_params, self.kv.buffers,
+                self.draft_kv.buffers, jnp.asarray(cur), jnp.asarray(prev),
+                jnp.asarray(u), jnp.asarray(sync_pos), jnp.asarray(tpos),
+                jnp.asarray(n_draft), self.kv.tables_device(),
+                self.draft_kv.tables_device())
+            self.kv.swap_buffers(self._verify_stream.ordered(buf))
+            self.draft_kv.swap_buffers(self._draft_stream.ordered(dbuf))
+            greedy_np = np.asarray(greedy)     # the one host sync per round
+            n_emit_np = np.asarray(n_emit)
             # the round's modeled price is per live row; the measured
             # twin is the fused dispatch + its one host sync
-            tr.hop("spec_verify", cost * max(1, len(live)), t_disp,
-                   time.perf_counter(), rows=len(live), k=k)
+            sp.hop("spec_verify", cost * max(1, len(live)))
         finished: List[ServeRequest] = []
         for slot in live:
             req = self._slot_req[slot]

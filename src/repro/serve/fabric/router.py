@@ -30,7 +30,6 @@ eligible (:mod:`~repro.serve.fabric.placement`):
 
 from __future__ import annotations
 
-import time
 import warnings
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
@@ -40,11 +39,12 @@ import numpy as np
 
 from repro.core.comm import ThreadComm, threadcomm_init
 from repro.core.compat import make_mesh
-# telemetry (REPRO_TRACE=1, DESIGN.md §15): dispatch/migrate hop spans
-# with modeled-vs-measured residuals — one global read + None check off
+# telemetry (DESIGN.md §15): dispatch/migrate hop spans with modeled-vs-
+# measured residuals, and the lockstep join's span
 from repro.obs import flush_trial as _obs_flush_trial
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import active as _tr_active
+from repro.obs import trace as obs_trace
+from repro.obs.trace import span
 from repro.serve.engine import ContinuousEngine
 from repro.serve.kv_cache import LeaseLeakError, LeaseLeakWarning
 from repro.serve.fabric.placement import Placement, make_placement
@@ -206,27 +206,19 @@ class ServingFabric:
     def _dispatch(self, now: float) -> None:
         """Deal queued requests join-shortest-queue to eligible ranks,
         stopping at the dispatch window (bounded per-rank backlog)."""
-        tr = _tr_active()
         while True:
             w = self.placement.select_submit(self.workers)
             if w is None or w.queue_depth >= self.dispatch_window:
                 return
-            if tr is None:
+            with span("fabric.dispatch", dst=w.rank) as sp:
                 admitted = self.scheduler.admit(now, 1)
                 if not admitted:
                     return
                 w.submit(admitted[0], now)
-            else:
                 # the router-dispatch hop's wall-clock twin of the §3.2
                 # admission price stamped at this hop's scheduler
-                t0 = time.perf_counter()
-                admitted = self.scheduler.admit(now, 1)
-                if not admitted:
-                    return
-                w.submit(admitted[0], now)
-                tr.hop("router_dispatch", admitted[0].admit_cost_s, t0,
-                       time.perf_counter(), rid=admitted[0].rid,
-                       rank=w.rank)
+                sp.hop("router_dispatch", admitted[0].admit_cost_s)
+                sp.set(rid=admitted[0].rid)
 
     # -- the migrate hop (disaggregated only) ------------------------------
     def _migrate(self, now: float) -> None:
@@ -249,23 +241,19 @@ class ServingFabric:
                     held.extend(pending[i:])   # FIFO: defer the rest too
                     break
                 slot = None
-                tr = _tr_active()
-                t0 = time.perf_counter() if tr is not None else 0.0
                 try:
-                    slot, dst_blocks = d.engine.begin_import(h.req)
-                    state_row = jax.device_put(
-                        w.engine.handoff_state(h.slot), d.device)
-                    cost = self.transport.migrate(
-                        w.engine.kv, d.engine.kv, h.blocks,
-                        dst_blocks[:len(h.blocks)])
-                    d.engine.finish_import(slot, h, state_row, now)
-                    if tr is not None:
-                        # the migrate hop's wall-clock twin: posted
-                        # receive + block messages + waitall + install
-                        tr.hop("migration", cost, t0,
-                               time.perf_counter(), rid=h.req.rid,
-                               src=w.rank, dst=d.rank,
-                               blocks=len(h.blocks))
+                    # the migrate hop's wall-clock twin: posted receive +
+                    # block messages + waitall + install
+                    with span("fabric.migrate", rid=h.req.rid, src=w.rank,
+                              dst=d.rank, blocks=len(h.blocks)) as sp:
+                        slot, dst_blocks = d.engine.begin_import(h.req)
+                        state_row = jax.device_put(
+                            w.engine.handoff_state(h.slot), d.device)
+                        cost = self.transport.migrate(
+                            w.engine.kv, d.engine.kv, h.blocks,
+                            dst_blocks[:len(h.blocks)])
+                        d.engine.finish_import(slot, h, state_row, now)
+                        sp.hop("migration", cost)
                 except BaseException:
                     # an error mid-migration must not lose in-flight
                     # requests: undo the posted receive and put this
@@ -293,21 +281,21 @@ class ServingFabric:
         Dispatch and migration stay on the router thread: they read and
         write cross-rank state (JSQ loads, block leases on two pools),
         while a rank's micro-step touches only its own."""
-        tr = _tr_active()
-        if tr is not None:
-            # router-thread runnable hint: queued requests the router
-            # could be dispatching — time it then spends blocked inside
-            # a migrate waitall is measured serialization (paper §2)
-            tr.set_runnable(self.scheduler.num_waiting)
+        # router-thread runnable hint: queued requests the router could
+        # be dispatching — time it then spends blocked inside a migrate
+        # waitall is measured serialization (paper §2)
+        obs_trace.set_runnable(self.scheduler.num_waiting)
         self._dispatch(now)
         finished: List[ServeRequest] = []
-        if self._rank_pool is not None:
-            for done in self._rank_pool.map(
-                    lambda w: w.step(now), self.workers):
-                finished.extend(done)
-        else:
-            for w in self.workers:
-                finished.extend(w.step(now))
+        # the lockstep join: the router waits here for every rank's step
+        with span("fabric.step", ranks=len(self.workers)):
+            if self._rank_pool is not None:
+                for done in self._rank_pool.map(
+                        lambda w: w.step(now), self.workers):
+                    finished.extend(done)
+            else:
+                for w in self.workers:
+                    finished.extend(w.step(now))
         if self.placement.needs_migration:
             self._migrate(now)
         self.finished.extend(finished)

@@ -32,7 +32,6 @@ traffic.
 
 from __future__ import annotations
 
-import time
 from typing import List
 
 import jax
@@ -41,7 +40,7 @@ import jax.numpy as jnp
 from repro.analysis.sanitizer import active as _san_active
 from repro.core import protocol
 from repro.core.comm import Request, waitall
-from repro.obs.trace import active as _tr_active
+from repro.obs.trace import span
 
 
 class KVBlockTransport:
@@ -136,53 +135,48 @@ class KVBlockTransport:
         san = _san_active()
         if san is not None:
             san.on_migrate_begin(self, len(src_blocks))
-        tr = _tr_active()
-        t_xfer = time.perf_counter() if tr is not None else 0.0
-        # the first hop donates the live destination pool, so from here
-        # on dst_kv MUST end up pointing at the freshest chain value
-        # whatever happens — on an error mid-chain or at completion the
-        # old buffers are already gone, and leaving dst_kv on them would
-        # crash every later decode step with a deleted-array error that
-        # masks the real failure
-        try:
-            for sb, db in zip(src_blocks, dst_blocks):
-                dst, probe = self._copy(dst, src_kv.buffers,
-                                        jnp.int32(sb), jnp.int32(db))
-                # the probe (read out of the freshly written block,
-                # inside the same jit) joins the migrate stream's
-                # program order — MPIX-stream serialization of the
-                # per-block sends — and rides the Request whose wait()
-                # is the block's completion point; the pool itself is
-                # serialized by the donation chain and must not be
-                # pinned by a request (the next hop deletes it)
-                probe = stream.ordered(probe)
-                requests.append(Request(
-                    self.comm, f"kv_block[{proto}]", probe,
-                    stream=stream,
-                    model_overhead_s=protocol.request_overhead(nb, proto)))
-        finally:
-            # request-leak: completion must sit on the exception path
-            # too — an error mid-chain used to abandon every block
-            # message already in flight (their Requests died unwaited at
-            # the next finish()); the issued prefix is always valid, so
-            # complete it before the pool install either way
+        # the pure block-transfer span; it nests inside the router's
+        # fabric.migrate span, which also covers the lease import
+        with span("fabric.kv_transfer", blocks=len(src_blocks)):
+            # the first hop donates the live destination pool, so from here
+            # on dst_kv MUST end up pointing at the freshest chain value
+            # whatever happens — on an error mid-chain or at completion the
+            # old buffers are already gone, and leaving dst_kv on them would
+            # crash every later decode step with a deleted-array error that
+            # masks the real failure
             try:
-                waitall(requests)          # completion before install
-                if san is not None and len(requests) == len(src_blocks):
-                    san.on_migrate_end(self)
+                for sb, db in zip(src_blocks, dst_blocks):
+                    dst, probe = self._copy(dst, src_kv.buffers,
+                                            jnp.int32(sb), jnp.int32(db))
+                    # the probe (read out of the freshly written block,
+                    # inside the same jit) joins the migrate stream's
+                    # program order — MPIX-stream serialization of the
+                    # per-block sends — and rides the Request whose wait()
+                    # is the block's completion point; the pool itself is
+                    # serialized by the donation chain and must not be
+                    # pinned by a request (the next hop deletes it)
+                    probe = stream.ordered(probe)
+                    requests.append(Request(
+                        self.comm, f"kv_block[{proto}]", probe,
+                        stream=stream,
+                        model_overhead_s=protocol.request_overhead(nb, proto)))
             finally:
-                dst_kv.swap_buffers(dst)
+                # request-leak: completion must sit on the exception path
+                # too — an error mid-chain used to abandon every block
+                # message already in flight (their Requests died unwaited at
+                # the next finish()); the issued prefix is always valid, so
+                # complete it before the pool install either way
+                try:
+                    waitall(requests)          # completion before install
+                    if san is not None and len(requests) == len(src_blocks):
+                        san.on_migrate_end(self)
+                finally:
+                    dst_kv.swap_buffers(dst)
         moved = len(src_blocks)
         # the model already charges each block's request object inside
         # its per-block message price — the Request.model_overhead_s
         # fields are the per-message view of the same cost, not an add-on
         cost = protocol.kv_migration_latency(moved * nb, nb)
-        if tr is not None:
-            # the pure block-transfer span; it nests (by timestamp
-            # containment) inside the router's hop:migration event,
-            # which also covers the lease import bookkeeping
-            tr.complete("kv_transfer", t_xfer, time.perf_counter(),
-                        cat="fabric", blocks=moved)
         self.n_migrations += 1
         self.n_blocks_moved += moved
         self.bytes_moved += moved * nb

@@ -18,11 +18,11 @@ from typing import Dict, List
 import jax
 
 from repro.core import protocol
-# telemetry (REPRO_TRACE=1, DESIGN.md §15): each rank step runs inside a
-# rank scope so every span a pool thread emits lands on the right
-# Perfetto lane (threads are re-assigned to ranks arbitrarily per step)
+# telemetry (DESIGN.md §15): each rank step is a span with ``rank=``, so
+# the engine spans nested in it belong to that rank (pool threads are
+# re-assigned to ranks arbitrarily per step)
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import active as _tr_active
+from repro.obs.trace import span
 from repro.serve.engine import ContinuousEngine
 from repro.serve.scheduler import ServeRequest
 
@@ -145,15 +145,10 @@ class EngineWorker:
     # -- micro-step --------------------------------------------------------
     def step(self, now: float = 0.0) -> List[ServeRequest]:
         busy = not self.idle
-        tr = _tr_active()
-        with jax.default_device(self.device):
-            if tr is None:
-                finished = self.engine.step(now)
-            else:
-                with tr.rank_scope(self.rank), \
-                        tr.span("rank_step", cat="fabric", rank=self.rank,
-                                role=self.role, busy=busy):
-                    finished = self.engine.step(now)
+        with jax.default_device(self.device), \
+                span("fabric.rank_step", rank=self.rank, role=self.role,
+                     busy=busy):
+            finished = self.engine.step(now)
         self.total_steps += 1
         self.busy_steps += int(busy)
         self.n_finished += len(finished)

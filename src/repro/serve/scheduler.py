@@ -38,10 +38,10 @@ from typing import Deque, Dict, List, Optional
 import numpy as np
 
 from repro.core import protocol
-# telemetry (REPRO_TRACE=1, DESIGN.md §15): admit/defer instants and the
-# admission counters — one global read + None check when off
+# telemetry (REPRO_TRACE=1, DESIGN.md §15): the admission counters — one
+# global read + None check when off. Admissions and deferrals show in the
+# engine's spans (``serve.admit``; ``deferred=`` on ``serve.step``).
 from repro.obs.metrics import active as _reg_active
-from repro.obs.trace import active as _tr_active
 
 #: scheduler classes mapped from the protocol model
 EAGER_CLASS = ("eager_fast", "eager")
@@ -314,7 +314,6 @@ class CellQueueScheduler:
         (FIFO is preserved; small latecomers must not starve a large
         prompt that is already at the head)."""
         out: List[ServeRequest] = []
-        tr = _tr_active()
         while free_slots > 0:
             if self._cellq:
                 queue = self._cellq
@@ -325,9 +324,6 @@ class CellQueueScheduler:
             req = queue[0]
             if can_admit is not None and not can_admit(req):
                 self.n_block_deferrals += 1
-                if tr is not None:
-                    tr.instant("defer", cat="sched", rid=req.rid,
-                               reason="blocks")
                 break
             queue.popleft()
             if queue is self._cellq:
@@ -335,9 +331,6 @@ class CellQueueScheduler:
                 self._promote()
             req.admit_time = now
             out.append(req)
-            if tr is not None:
-                tr.instant("admit", cat="sched", rid=req.rid,
-                           protocol=req.protocol)
             free_slots -= 1
         reg = _reg_active()
         if reg is not None:

@@ -1,17 +1,23 @@
-"""Telemetry subsystem (DESIGN.md §15): tracer span nesting and rank
-attribution under pool threads, ring-buffer overflow semantics, Chrome
-``trace_event`` export validity, the residual ledger + serialization-
-stall detector, the metrics registry, trial-flush wiring on engine
-reset / fabric close, and the inert-when-disabled contract (mirroring
-``test_sanitizer.py``: instrumented code pays one ``None`` check and
-nothing else when telemetry is off)."""
+"""Telemetry subsystem (DESIGN.md §15): profiler-backed spans (nesting,
+late arguments, rank attribution under pool threads, the Perfetto
+export), the engine's step phases on the profiler's clock and in
+``last_step_phases``, the model's named scopes in the decode program,
+the residual ledger + serialization-stall detector, the metrics
+registry, trial-flush wiring on engine reset / fabric close, and the
+inert-when-disabled contract (no profiler session: a span records
+nothing; no ledger: one ``None`` check)."""
 
+import glob
+import gzip
 import json
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
+import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.config import ServeConfig, TrainConfig
 from repro.configs import get_smoke_config
@@ -21,12 +27,16 @@ from repro.obs import residuals as R
 from repro.obs import trace as T
 from repro.serve import ContinuousEngine, ServingFabric
 
+PHASES = ("serve.admit", "serve.chunk.build", "serve.chunk.dispatch",
+          "serve.chunk.sync", "serve.chunk.retire", "serve.decode.dispatch",
+          "serve.decode.sync", "serve.decode.retire")
+
 
 @pytest.fixture
-def tracer():
-    tr = T.install(capacity=4096)
+def ledger():
+    led = T.install()
     M.install()
-    yield tr
+    yield led
     T.uninstall()
     M.uninstall()
 
@@ -50,146 +60,282 @@ def bundle():
     return cfg, model, model.init(jax.random.PRNGKey(0))
 
 
+def _paged_engine(bundle, **kw):
+    cfg, model, params = bundle
+    return ContinuousEngine(model, params, cache_len=48, num_slots=2,
+                            prefill_chunk=16, kv_layout="paged",
+                            block_size=8, **kw)
+
+
+def _host_spans(trace_dir, prefixes=("serve.", "fabric.", "comm.", "t.")):
+    """``(name, start_ns, end_ns, args, thread)`` of the profile's host
+    spans whose names start with ``prefixes``, in start order."""
+    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
+    out = []
+    for plane in ProfileData.from_file(path[-1]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for thread, line in enumerate(plane.lines):
+            out.extend((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        dict(e.stats), thread) for e in line.events
+                       if e.name.startswith(prefixes))
+    return sorted(out, key=lambda s: s[1])
+
+
+def _within(inner, outer):
+    return (inner[4] == outer[4] and outer[1] <= inner[1]
+            and inner[2] <= outer[2])
+
+
 # ---------------------------------------------------------------------------
-# span nesting and rank attribution
+# spans on the profiler's clock
 # ---------------------------------------------------------------------------
 
-def test_span_nesting_parent_recorded(tracer):
-    with tracer.span("outer", cat="test"):
-        with tracer.span("inner", cat="test", k=1):
-            pass
-    by_name = {e["name"]: e for e in tracer.events()}
-    assert by_name["inner"]["args"]["parent"] == "outer"
-    assert "parent" not in by_name["outer"]["args"]
-    assert by_name["inner"]["ph"] == "X"
-    assert by_name["inner"]["dur"] >= 0.0
-    assert tracer.unbalanced == 0
+def test_span_nesting_parent_recorded(tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        with T.span("t.outer", k=1):
+            with T.span("t.inner", k=2):
+                time.sleep(0.001)
+    spans = {s[0]: s for s in _host_spans(tmp_path)}
+    outer, inner = spans["t.outer"], spans["t.inner"]
+    assert _within(inner, outer)
+    assert inner[2] - inner[1] >= 1e6
+    assert outer[3] == {"k": 1} and inner[3] == {"k": 2}
 
 
-def test_complete_inherits_open_parent(tracer):
-    with tracer.span("outer"):
-        t0 = time.perf_counter()
-        tracer.complete("hot", t0, time.perf_counter())
-    ev = [e for e in tracer.events() if e["name"] == "hot"][0]
-    assert ev["args"]["parent"] == "outer"
+def test_span_phases_accumulate_by_name():
+    phases = {}
+    for _ in range(3):
+        with T.span("t.x", phases) as sp:
+            time.sleep(0.001)
+    with T.span("t.y", phases):
+        pass
+    assert set(phases) == {"t.x", "t.y"}
+    assert phases["t.x"] >= 3e-3
+    assert sp.seconds >= 1e-3 and phases["t.x"] >= sp.seconds
 
 
-def test_manual_end_is_idempotent(tracer):
-    sp = tracer.span("once")
-    sp.end()
-    sp.end()
-    assert len([e for e in tracer.events() if e["name"] == "once"]) == 1
-    assert tracer.unbalanced == 0
+def test_manual_enter_end_records_once(tmp_path):
+    """The CommStream shape: entered in ``__enter__``, ended in
+    ``__exit__`` of another object."""
+    with jax.profiler.trace(str(tmp_path)):
+        sp = T.span("t.manual", stream="decode")
+        sp.__enter__()
+        sp.end()
+    spans = [s for s in _host_spans(tmp_path) if s[0] == "t.manual"]
+    assert len(spans) == 1 and spans[0][3] == {"stream": "decode"}
 
 
-def test_out_of_order_end_counted_unbalanced(tracer):
-    a = tracer.span("a")
-    b = tracer.span("b")
-    a.end()              # LIFO violation: b is still open
-    b.end()
-    assert tracer.unbalanced == 1
-    assert len(tracer.events()) == 2
+def test_span_exception_ends_and_prices_nothing(ledger, tmp_path):
+    phases = {}
+    with jax.profiler.trace(str(tmp_path)):
+        with pytest.raises(RuntimeError):
+            with T.span("t.fails", phases) as sp:
+                sp.hop("migration", 1e-3)
+                raise RuntimeError("mid-hop")
+    assert ledger.counts() == {}
+    assert "t.fails" in phases
+    (span,) = [s for s in _host_spans(tmp_path) if s[0] == "t.fails"]
+    assert "hop" not in span[3]
 
 
-def test_rank_attribution_under_pool_threads(tracer):
+def test_rank_attribution_under_pool_threads(ledger, tmp_path):
     """Fabric shape: a ThreadPoolExecutor re-assigns threads to ranks
-    arbitrarily per step; rank_scope must pin every event to the rank,
-    and the thread-local stacks must never cross-corrupt."""
+    arbitrarily per step; the rank-step span's ``rank=`` must tag the
+    span and every priced hop inside it, with no cross-thread leak."""
+    seen = []
+
     def one_step(rank, step):
-        with tracer.rank_scope(rank):
-            with tracer.span(f"step:{rank}", step=step):
-                with tracer.span(f"sub:{rank}"):
-                    time.sleep(0.0005)
+        with T.span("t.rank_step", rank=rank, step=step):
+            with T.span("t.sub") as sp:
+                sp.hop("admission", 1e-3)
+                seen.append((rank, T.current_rank()))
+                time.sleep(0.0005)
+        assert T.current_rank() is None
 
-    with ThreadPoolExecutor(max_workers=3,
-                            thread_name_prefix="fabric-rank") as ex:
-        futs = [ex.submit(one_step, rank, step)
-                for step in range(8) for rank in range(4)]
-        for f in futs:
-            f.result()
-    assert tracer.unbalanced == 0
-    for ev in tracer.events():
-        kind, _, rank = ev["name"].partition(":")
-        assert ev["tid"] == int(rank)        # lane == rank, not thread
-        if kind == "sub":
-            assert ev["args"]["parent"] == f"step:{rank}"
-    # 4 ranks x 8 steps x 2 spans
-    assert len(tracer.events()) == 64
-
-
-def test_driver_lane_outside_rank_scope(tracer):
-    tracer.instant("driver_event")
-    ev = tracer.events()[0]
-    assert ev["tid"] >= T.DRIVER_TID
-    lanes = tracer.chrome_trace()["traceEvents"]
-    names = {m["tid"]: m["args"]["name"] for m in lanes
-             if m.get("ph") == "M" and m["name"] == "thread_name"}
-    assert ev["tid"] in names                # lane carries a thread name
+    with jax.profiler.trace(str(tmp_path)):
+        with ThreadPoolExecutor(max_workers=3,
+                                thread_name_prefix="fabric-rank") as ex:
+            for f in [ex.submit(one_step, rank, step)
+                      for step in range(8) for rank in range(4)]:
+                f.result()
+    assert all(rank == cur for rank, cur in seen)
+    steps = [s for s in _host_spans(tmp_path) if s[0] == "t.rank_step"]
+    subs = [s for s in _host_spans(tmp_path) if s[0] == "t.sub"]
+    assert len(steps) == len(subs) == 32
+    assert sorted(s[3]["rank"] for s in steps) == sorted(list(range(4)) * 8)
+    for sub in subs:              # each sub nests in one rank step
+        assert sum(_within(sub, st) for st in steps) == 1
+    with ledger._lock:
+        ranks = sorted(r for _, _, r in ledger._hops["admission"])
+    assert ranks == sorted(list(range(4)) * 8)
 
 
-# ---------------------------------------------------------------------------
-# ring buffer
-# ---------------------------------------------------------------------------
-
-def test_ring_overflow_drops_oldest_first():
-    tr = T.Tracer(capacity=8)
-    for i in range(12):
-        tr.instant(f"ev{i}")
-    names = [e["name"] for e in tr.events()]
-    assert names == [f"ev{i}" for i in range(4, 12)]
-    assert tr.dropped == 4
-    assert tr.chrome_trace()["metadata"]["dropped_events"] == 4
+def test_no_rank_outside_rank_span(ledger):
+    assert T.current_rank() is None
+    with T.span("t.router") as sp:
+        sp.hop("router_dispatch", 1e-4)
+    with ledger._lock:
+        assert ledger._hops["router_dispatch"][0][2] == -1
 
 
-def test_ring_capacity_validated():
-    with pytest.raises(ValueError):
-        T.Tracer(capacity=0)
+def test_profile_keeps_every_span(tmp_path):
+    """No ring: every span of a long session is in the profile."""
+    with jax.profiler.trace(str(tmp_path)):
+        for i in range(3000):
+            with T.span("t.many", i=i):
+                pass
+    spans = [s for s in _host_spans(tmp_path) if s[0] == "t.many"]
+    assert [s[3]["i"] for s in spans] == list(range(3000))
 
 
-# ---------------------------------------------------------------------------
-# Chrome trace_event export
-# ---------------------------------------------------------------------------
-
-def test_chrome_trace_valid_json(tracer, tmp_path):
-    with tracer.rank_scope(1):
-        with tracer.span("rank_step", cat="fabric"):
-            t0 = time.perf_counter()
-            tracer.complete("decode", t0, time.perf_counter(), rows=2)
-        tracer.counter("block_pool", free=3, live=5)
-    tracer.instant("admit", cat="sched", rid=0)
-    path = tmp_path / "trace.json"
-    tracer.write_chrome(str(path))
-    doc = json.loads(path.read_text())
-    assert doc["displayTimeUnit"] == "ms"
-    evs = doc["traceEvents"]
-    assert {"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
-            "args": {"name": "repro-serve"}} in evs
-    # rank lane named and sorted
-    assert any(m.get("ph") == "M" and m["name"] == "thread_name"
-               and m["tid"] == 1 and m["args"]["name"] == "rank 1"
-               for m in evs)
-    data = [e for e in evs if e.get("ph") != "M"]
-    assert [e["ts"] for e in data] == sorted(e["ts"] for e in data)
-    for e in data:
-        assert {"name", "ph", "ts", "pid", "tid"} <= set(e)
-        if e["ph"] == "X":
-            assert e["dur"] >= 0.0
+def test_late_arguments_land_on_the_span(tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        with T.span("t.late", rid=3) as sp:
+            sp.set(prefix_hit_tokens=16, finished=2)
+    (span,) = [s for s in _host_spans(tmp_path) if s[0] == "t.late"]
+    assert span[3] == {"rid": 3, "prefix_hit_tokens": 16, "finished": 2}
 
 
-def test_hop_emits_span_and_residual(tracer):
-    t0 = time.perf_counter()
-    time.sleep(0.001)
-    with tracer.rank_scope(2):
-        tracer.hop("migration", 0.5e-3, t0, time.perf_counter(), rid=7)
-    ev = [e for e in tracer.events() if e["name"] == "hop:migration"][0]
-    assert ev["cat"] == "residual"
-    assert ev["args"]["modeled_s"] == pytest.approx(0.5e-3)
-    assert ev["args"]["measured_s"] > 0.0
-    assert ev["args"]["residual_ratio"] == pytest.approx(
-        ev["args"]["measured_s"] / 0.5e-3)
-    assert ev["tid"] == 2
-    rep = tracer.residuals.report()
+def test_chrome_trace_valid_json(tmp_path):
+    """``--trace-out``'s export: the profiler's Perfetto trace parses as
+    trace_event JSON with each span's arguments."""
+    with jax.profiler.trace(str(tmp_path), create_perfetto_trace=True):
+        with T.span("t.rank_step", rank=1, role="full"):
+            with T.span("t.decode", rows=2):
+                pass
+    (path,) = glob.glob(f"{tmp_path}/**/perfetto_trace.json.gz",
+                        recursive=True)
+    doc = json.loads(gzip.open(path).read())
+    evs = {e["name"]: e for e in doc["traceEvents"]
+           if e.get("name", "").startswith("t.")}
+    assert evs["t.rank_step"]["ph"] == "X"
+    assert evs["t.rank_step"]["args"] == {"rank": "1", "role": "full"}
+    assert evs["t.decode"]["args"] == {"rows": "2"}
+    assert evs["t.decode"]["dur"] <= evs["t.rank_step"]["dur"]
+
+
+def test_hop_emits_span_and_residual(ledger, tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        with T.span("t.rank_step", rank=2):
+            with T.span("t.migrate", rid=7) as sp:
+                time.sleep(0.001)
+                sp.hop("migration", 0.5e-3)
+    (span,) = [s for s in _host_spans(tmp_path) if s[0] == "t.migrate"]
+    args = span[3]
+    assert args["hop"] == "migration" and args["rid"] == 7
+    assert args["modeled_s"] == pytest.approx(0.5e-3)
+    assert args["measured_s"] >= 1e-3
+    rep = ledger.report()
     assert rep["hops"]["migration"]["n"] == 1
+    assert rep["hops"]["migration"]["measured_s"] == pytest.approx(
+        args["measured_s"])
+    with ledger._lock:
+        assert ledger._hops["migration"][0][2] == 2
+
+
+# ---------------------------------------------------------------------------
+# engine phases and model scopes
+# ---------------------------------------------------------------------------
+
+def _prompt(cfg, n):
+    return np.arange(n, dtype=np.int32)[None] % cfg.vocab_size
+
+
+def test_engine_phases_in_profile(bundle, tmp_path):
+    """A profiled paged engine: every phase nests in a ``serve.step`` on
+    the same thread, admission carries the request, the step its rows."""
+    cfg = bundle[0]
+    eng = _paged_engine(bundle)
+    eng.generate({"tokens": _prompt(cfg, 24)}, 3)          # compile
+    eng.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        eng.generate({"tokens": np.concatenate([_prompt(cfg, 24)] * 2)}, 4)
+    spans = _host_spans(tmp_path, ("serve.",))
+    steps = [s for s in spans if s[0] == "serve.step"]
+    assert steps and {"rows", "prefilling", "decoding", "blocks_free",
+                      "deferred", "finished"} <= set(steps[0][3])
+    assert max(s[3]["rows"] for s in steps) == 2
+    names = {s[0] for s in spans}
+    assert set(PHASES) <= names
+    for sp in spans:
+        if sp[0] != "serve.step":
+            assert sum(_within(sp, st) for st in steps) == 1, sp[0]
+    admits = [s[3] for s in spans if s[0] == "serve.admit"]
+    assert sorted(a["rid"] for a in admits) == [0, 1]
+    assert all(a["prompt_len"] == 24 and a["prefix_hit_tokens"] == 0
+               and a["waited_s"] >= 0 for a in admits)
+
+
+def test_last_step_phases_without_profiler(bundle):
+    cfg = bundle[0]
+    eng = _paged_engine(bundle)
+    req_tokens = _prompt(cfg, 20)
+    from repro.serve import ServeRequest
+    eng.submit(ServeRequest(rid=0, batch={"tokens": req_tokens},
+                            max_new_tokens=4), 0.0)
+    eng.step(0.0)                        # admit + first chunk
+    first = eng.last_step_phases
+    assert {"serve.step", "serve.admit", "serve.chunk.build",
+            "serve.chunk.dispatch", "serve.chunk.retire"} <= set(first)
+    eng.step(0.0)                        # last chunk, then a decode
+    phases = eng.last_step_phases
+    assert phases is not first
+    assert {"serve.chunk.sync", "serve.decode.dispatch",
+            "serve.decode.sync", "serve.decode.retire"} <= set(phases)
+    inner = sum(v for k, v in phases.items() if k != "serve.step")
+    assert 0 < inner <= phases["serve.step"]
+
+
+def test_decode_program_ops_carry_scopes(bundle):
+    """The paged programs' optimized HLO names each op's scope in its
+    ``op_name`` metadata: the map a profile's device ops are read by."""
+    eng = _paged_engine(bundle)
+    eng.generate({"tokens": _prompt(bundle[0], 20)}, 2)
+    counts = eng.prefill_compiles, eng.decode_compiles
+    text = eng.program_text()
+    assert (eng.prefill_compiles, eng.decode_compiles) == counts
+    assert set(text) == {"_decode_traced", "_chunk_traced"}
+    for fn, hlo in text.items():
+        for scope in ("attention", "mlp", "norm", "lm_head", "sample",
+                      "layers"):
+            assert f"/{scope}/" in hlo, (fn, scope)
+
+
+@pytest.mark.parametrize("placement", ["replicated", "disagg"])
+def test_fabric_spans_in_profile(ledger, bundle, tmp_path, placement):
+    """The router's dispatch and migrate hops, each rank's step (with
+    ``rank=``) and the lockstep join are spans; engine steps nest in
+    their rank's step."""
+    from repro.serve import ServeRequest
+    cfg, model, params = bundle
+    fab = ServingFabric(model, params, ranks=2, placement=placement,
+                        cache_len=48, slots_per_rank=2, prefill_chunk=16,
+                        block_size=8)
+    try:
+        for rid in range(4):
+            fab.submit(ServeRequest(rid=rid, batch={"tokens": _prompt(
+                cfg, 20 + rid)}, max_new_tokens=3), 0.0)
+        with jax.profiler.trace(str(tmp_path)):
+            while not fab.idle:
+                fab.step(0.0)
+    finally:
+        fab.close()
+    spans = _host_spans(tmp_path)
+    ranks = [s for s in spans if s[0] == "fabric.rank_step"]
+    assert sorted({s[3]["rank"] for s in ranks}) == [0, 1]
+    assert any(s[0] == "fabric.step" for s in spans)
+    hops = {s[3]["hop"] for s in spans if "hop" in s[3]}
+    want = {"router_dispatch", "admission"}
+    if placement == "disagg":
+        want.add("migration")
+        assert any(s[0] == "fabric.kv_transfer" for s in spans)
+    assert want <= hops
+    for s in spans:
+        if "hop" in s[3]:
+            assert {"modeled_s", "measured_s"} <= set(s[3])
+    for st in (s for s in spans if s[0] == "serve.step"):
+        assert sum(_within(st, r) for r in ranks) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -222,18 +368,22 @@ def test_residual_under_factor_flagged_too():
     assert led.report()["flagged"] == ["spec_verify"]
 
 
-def test_stall_detector_gated_on_runnable(tracer):
-    t0 = time.perf_counter()
-    t1 = t0 + 2e-3
-    tracer.on_wait("allreduce", t0, t1)           # no runnable hint: idle
-    assert tracer.residuals.report()["serialization_stall_s"] == 0.0
-    tracer.set_runnable(3)
-    tracer.on_wait("allreduce", t0, t1)           # blocked while runnable
-    rep = tracer.residuals.report()
+def test_stall_detector_gated_on_runnable(ledger):
+    def waits():
+        T.set_runnable(0)
+        T.on_wait(2e-3)                           # no runnable work: idle
+        assert ledger.report()["serialization_stall_s"] == 0.0
+        T.set_runnable(3)
+        with T.span("t.rank_step", rank=1):
+            T.on_wait(2e-3)                       # blocked while runnable
+
+    th = threading.Thread(target=waits)
+    th.start()
+    th.join()
+    rep = ledger.report()
     assert rep["serialization_stall_s"] == pytest.approx(2e-3)
     assert rep["stall_events"] == 1
-    waits = [e for e in tracer.events() if e["name"] == "wait:allreduce"]
-    assert len(waits) == 2 and waits[1]["args"]["runnable"] == 3
+    assert rep["stall_by_rank"] == {"1": pytest.approx(2e-3)}
 
 
 def test_merge_reports_recombines_sums():
@@ -256,7 +406,7 @@ def test_merge_reports_recombines_sums():
 # metrics registry
 # ---------------------------------------------------------------------------
 
-def test_registry_counter_gauge_histogram(tracer):
+def test_registry_counter_gauge_histogram(ledger):
     reg = M.active()
     reg.counter("sched.admitted").inc(3)
     reg.counter("sched.admitted").inc()
@@ -274,7 +424,7 @@ def test_registry_counter_gauge_histogram(tracer):
                               "histograms": {}}
 
 
-def test_snapshot_merges_registry_and_extra(tracer):
+def test_snapshot_merges_registry_and_extra(ledger):
     M.active().counter("tokens_out").inc(5)
     out = M.snapshot(extra={"tok_s": 12.0})
     assert out["tok_s"] == 12.0
@@ -285,36 +435,30 @@ def test_snapshot_merges_registry_and_extra(tracer):
 # trial-flush wiring (the PR 5 req_log aliasing class)
 # ---------------------------------------------------------------------------
 
-def test_engine_reset_flushes_trial(tracer, bundle):
-    cfg, model, params = bundle
-    eng = ContinuousEngine(model, params, cache_len=32, num_slots=2,
-                           prefill_chunk=16, kv_layout="paged",
-                           block_size=8)
-    tracer.residuals.record("admission", 1e-3, 5e-3)   # warm-up pollution
+def test_engine_reset_flushes_trial(ledger, bundle):
+    eng = _paged_engine(bundle)
+    ledger.record("admission", 1e-3, 5e-3)        # warm-up pollution
     M.active().counter("tokens_out").inc(9)
     eng.reset()
-    assert tracer.residuals.counts() == {}
+    assert ledger.counts() == {}
     assert M.active().snapshot()["counters"] == {}
 
 
-def test_engine_reset_preserve_prefix_flushes_too(tracer, bundle):
-    cfg, model, params = bundle
-    eng = ContinuousEngine(model, params, cache_len=32, num_slots=2,
-                           prefill_chunk=16, kv_layout="paged",
-                           block_size=8, prefix_cache=True)
-    tracer.residuals.record("prefix_hit", 1e-3, 1e-3)
+def test_engine_reset_preserve_prefix_flushes_too(ledger, bundle):
+    eng = _paged_engine(bundle, prefix_cache=True)
+    ledger.record("prefix_hit", 1e-3, 1e-3)
     eng.reset(preserve_prefix=True)
-    assert tracer.residuals.counts() == {}
+    assert ledger.counts() == {}
 
 
-def test_fabric_close_flushes_trial(tracer, bundle):
+def test_fabric_close_flushes_trial(ledger, bundle):
     cfg, model, params = bundle
     fab = ServingFabric(model, params, ranks=2, placement="replicated",
                         cache_len=32, slots_per_rank=2, prefill_chunk=16,
                         block_size=8)
-    tracer.residuals.record("router_dispatch", 1e-4, 1e-4)
+    ledger.record("router_dispatch", 1e-4, 1e-4)
     fab.close()
-    assert tracer.residuals.counts() == {}
+    assert ledger.counts() == {}
     assert fab.scheduler.req_log == {}
     assert fab.total_steps == 0
 
@@ -332,32 +476,48 @@ def test_fabric_speculate_requires_replicated(bundle):
 # ---------------------------------------------------------------------------
 
 def test_disabled_hooks_inert(off):
-    assert T.active() is None
+    assert T.ledger() is None
     assert M.active() is None
     T.flush_trial()                     # no-ops, no error
     M.flush_trial()
+    T.on_wait(1.0)
+    with T.span("t.unpriced") as sp:
+        sp.hop("admission", 1e-3)       # no ledger: nothing to record
 
 
 def test_disabled_guard_is_one_global_read(off):
-    """The instrumented-site pattern when telemetry is off: one module-
-    global read plus a None check. Bound it generously — the point is
-    that nothing allocates or reads the clock on the disabled path."""
+    """The ledger's sites when telemetry is off: one module-global read
+    plus a None check. Bound it generously — the point is that nothing
+    allocates or reads the clock on the disabled path."""
     n = 200_000
     t0 = time.perf_counter()
     for _ in range(n):
-        tr = T.active()
-        if tr is not None:              # pragma: no cover
-            tr.instant("never")
+        led = T.ledger()
+        if led is not None:             # pragma: no cover
+            led.record("never", 0.0, 0.0)
     dt = time.perf_counter() - t0
     assert dt / n < 5e-6                # < 5us per guard, vastly above cost
 
 
+def test_span_without_profiler_is_cheap(off):
+    """No profiler session: a span is one annotation that records
+    nothing plus two clock reads. Bound it generously — the point is that
+    nothing is kept."""
+    n = 50_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with T.span("t.off", rows=3):
+            pass
+    dt = time.perf_counter() - t0
+    assert dt / n < 20e-6
+
+
 def test_install_is_fresh_each_time():
-    tr1 = T.install(capacity=16)
-    tr1.instant("stale")
-    tr2 = T.install(capacity=16)
+    led1 = T.install()
+    led1.record("admission", 1e-3, 1e-3)
+    led2 = T.install()
     try:
-        assert T.active() is tr2
-        assert tr2.n_events == 0
+        assert T.ledger() is led2
+        assert led2.counts() == {}
     finally:
         T.uninstall()

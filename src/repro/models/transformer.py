@@ -22,6 +22,8 @@ from jax import lax
 
 from repro.config import (BLOCK_DENSE, BLOCK_HYBRID, BLOCK_MOE, BLOCK_SSM,
                           ModelConfig)
+from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention.paged_attention import pages_in_place
 from repro.models import layers as L
 from repro.models import mamba, moe
 
@@ -367,13 +369,14 @@ def make_prefill(cfg: ModelConfig, knobs, tp: int):
     return prefill
 
 
-def _masked_group_attention(cfg, p, q, keys, values, okay, out_dtype):
+def _masked_group_attention(cfg, q, keys, values, okay, out_dtype):
     """Shared grouped-attention core of the slot (ring) and paged
     (block-table) cached-attention paths: grouped scores, softcap,
-    additive NEG_INF mask, softmax, context, output projection. The two
-    paths differ only in how keys/values/mask are produced — the math
-    here MUST stay one copy or a softcap/masking fix could silently
-    diverge them and break the token-parity guarantee CI asserts.
+    additive NEG_INF mask, softmax, context heads (B,C,H,hd), before the
+    output projection. The two paths differ only in how keys/values/mask
+    are produced — the math here MUST stay one copy or a softcap/masking
+    fix could silently diverge them and break the token-parity guarantee
+    CI asserts.
 
     q (B,C,H,hd); keys/values (B,T,Gs,hd); okay broadcastable to
     (B,C,T).
@@ -389,8 +392,7 @@ def _masked_group_attention(cfg, p, q, keys, values, okay, out_dtype):
     s = s + jnp.where(okay, 0.0, L.NEG_INF)[:, None, None, :, :]
     prob = jax.nn.softmax(s, axis=-1).astype(out_dtype)
     ctx = jnp.einsum("bgrqt,btgk->bqgrk", prob, values)
-    ctx = ctx.reshape(B, C, cfg.num_heads, cfg.head_dim)
-    return L.attn_output(p, ctx, out_dtype)
+    return ctx.reshape(B, C, cfg.num_heads, cfg.head_dim)
 
 
 def _cached_attn(cfg, p, xn, layer_cache, qpos, wslot, is_global):
@@ -421,9 +423,9 @@ def _cached_attn(cfg, p, xn, layer_cache, qpos, wslot, is_global):
     if cfg.swa_window > 0:
         win_ok = kpos[None, :] > qpos[:, None] - cfg.swa_window
         okay = okay & jnp.where(is_global, True, win_ok)
-    out = _masked_group_attention(cfg, p, q, new_k, new_v, okay[None],
-                                  xn.dtype)
-    return out, {"k": new_k, "v": new_v, "pos": new_pos}
+    ctx = _masked_group_attention(cfg, q, new_k, new_v, okay[None], xn.dtype)
+    return L.attn_output(p, ctx, xn.dtype), {"k": new_k, "v": new_v,
+                                             "pos": new_pos}
 
 
 def _decode_attn(cfg, p, xn, layer_cache, pos, is_global, tp):
@@ -550,6 +552,52 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return c
 
 
+def paged_decode_path(kv_heads: int, head_dim: int, platform: str) -> str:
+    """The path single-token paged decode attention takes, lowered for
+    ``platform`` over pool pages of ``kv_heads`` x ``head_dim``:
+    ``"paged_kernel"`` (each row's live pages read in place,
+    :mod:`repro.kernels.paged_attention`) or ``"gather"`` (every row's
+    whole table gathered, :func:`_paged_gather_context`)."""
+    if platform == "tpu" and pages_in_place(kv_heads, head_dim):
+        return "paged_kernel"
+    return "gather"
+
+
+def _paged_gather_context(cfg, q, k_pool, v_pool, tables, qpos, window,
+                          out_dtype):
+    """Context heads (B,C,H,hd) of queries q (B,C,H,hd) at ``qpos``
+    against their rows' pages, gathered at the table's full width: token
+    t of row b at index t of a (B, NB*bs, Gs, hd) copy. ``window`` is None
+    or an int32 scalar, traced or not (0 = none)."""
+    B = q.shape[0]
+    P, bs, gs, hd = k_pool.shape
+    NB = tables.shape[1]
+    flat = jnp.maximum(tables, 0).reshape(-1)
+    kg = jnp.take(k_pool, flat, axis=0).reshape(B, NB * bs, gs, hd)
+    vg = jnp.take(v_pool, flat, axis=0).reshape(B, NB * bs, gs, hd)
+
+    kpos = jnp.arange(NB * bs)                        # structural positions
+    okay = (kpos[None, None, :] <= qpos[:, :, None]) \
+        & jnp.repeat(tables >= 0, bs, axis=1)[:, None, :]
+    if window is not None:
+        okay = okay & ((window <= 0)
+                       | (kpos[None, None, :] > qpos[:, :, None] - window))
+    return _masked_group_attention(cfg, q, kg, vg, okay, out_dtype)
+
+
+def _paged_kernel_context(cfg, q, k_pool, v_pool, tables, qpos, wvalid,
+                          window, *, interpret=False):
+    """:func:`_paged_gather_context` for one query a row (C == 1),
+    through the paged-attention kernel: each row reads its live pages in
+    place, up to its length (``qpos + 1``; 0 for a row that writes
+    nothing, which reads nothing and gets zeros)."""
+    lengths = jnp.where(wvalid[:, 0], qpos[:, 0] + 1, 0)
+    ctx = paged_attention(q[:, 0], k_pool, v_pool, tables, lengths,
+                          window=0 if window is None else window,
+                          softcap=cfg.logit_softcap, interpret=interpret)
+    return ctx[:, None]
+
+
 @jax.named_scope("attention")
 def _paged_attn(cfg, p, xn, layer_cache, tables, qpos, wvalid, is_global):
     """Attention for query tokens against (and into) the paged pool — the
@@ -565,13 +613,16 @@ def _paged_attn(cfg, p, xn, layer_cache, tables, qpos, wvalid, is_global):
     offset ``qpos % bs`` — parked/padded queries aim at the out-of-range
     block index ``P`` and the explicit ``mode="drop"`` discards them
     (default scatter semantics would wraparound-corrupt a live block).
-    Queries then attend over their own gathered pages, causally masked on
-    the *structural* positions (table entry i holds tokens [i*bs,
-    (i+1)*bs)) — stale pages of a block's previous owner are never at a
-    position <= qpos of the new owner, so block recycling needs no
-    blanking dispatch.
+    Queries then attend over their own pages, causally masked on the
+    *structural* positions (table entry i holds tokens [i*bs, (i+1)*bs))
+    — stale pages of a block's previous owner are never at a position
+    <= qpos of the new owner, so block recycling needs no blanking
+    dispatch. Single-token decode (C == 1) lowered for a TPU reads each
+    row's live pages in place through the paged-attention kernel
+    (:func:`paged_decode_path`); chunks, verify q-blocks and other
+    platforms gather each row's whole table.
     """
-    B = xn.shape[0]
+    B, C = xn.shape[:2]
     P, bs, gs, hd = layer_cache["k"].shape
     NB = tables.shape[1]
     q, k, v = L.project_qkv(p, xn, cfg, qpos)        # per-row rope positions
@@ -583,18 +634,22 @@ def _paged_attn(cfg, p, xn, layer_cache, tables, qpos, wvalid, is_global):
     new_k = layer_cache["k"].at[wblk, woff].set(kc, mode="drop")
     new_v = layer_cache["v"].at[wblk, woff].set(vc, mode="drop")
 
-    # gather this batch's pages: (B, NB*bs, Gs, hd), token t at index t
-    flat = jnp.maximum(tables, 0).reshape(-1)
-    kg = jnp.take(new_k, flat, axis=0).reshape(B, NB * bs, gs, hd)
-    vg = jnp.take(new_v, flat, axis=0).reshape(B, NB * bs, gs, hd)
+    # dynamic per-layer window (hymba's traced is_global): 0 = none
+    window = (jnp.where(is_global, 0, cfg.swa_window).astype(jnp.int32)
+              if cfg.swa_window > 0 else None)
 
-    kpos = jnp.arange(NB * bs)                        # structural positions
-    okay = (kpos[None, None, :] <= qpos[:, :, None]) \
-        & jnp.repeat(tables >= 0, bs, axis=1)[:, None, :]
-    if cfg.swa_window > 0:
-        win_ok = kpos[None, None, :] > qpos[:, :, None] - cfg.swa_window
-        okay = okay & jnp.where(is_global, True, win_ok)
-    out = _masked_group_attention(cfg, p, q, kg, vg, okay, xn.dtype)
+    def gather(*args):
+        return _paged_gather_context(cfg, *args[:-1], window, xn.dtype)
+
+    def kernel(*args):
+        return _paged_kernel_context(cfg, *args, window)
+
+    args = (q, new_k, new_v, tables, qpos, wvalid)
+    if C == 1 and pages_in_place(gs, hd):
+        ctx = lax.platform_dependent(*args, tpu=kernel, default=gather)
+    else:
+        ctx = gather(*args)
+    out = L.attn_output(p, ctx, xn.dtype)
     return out, {"k": new_k, "v": new_v}
 
 

@@ -12,9 +12,10 @@ leased through per-request block tables
 (:mod:`repro.serve.block_pool`, DESIGN.md §9): admission then gates on
 free blocks instead of free slots, prompts deposit chunk-by-chunk
 through the tables, and decode is the model's batched block-table step
-(`decode_step_paged`; the same computation's TPU hot-path kernel is
-``kernels/paged_attention`` — a standalone validated artifact like
-flash_attention, not yet dispatched from the model path).
+(`decode_step_paged`), whose attention on a TPU reads each row's live
+pages in place through ``kernels/paged_attention`` (the
+``serve.decode.dispatch`` span carries ``kv_pages``, ``table_pages``
+and ``attn_path``).
 Each host step admits requests from the cell-queue scheduler
 (:mod:`repro.serve.scheduler`), deposits their prompts, then advances
 every live slot by one token. Decode over the pool is a single jit'd
@@ -73,6 +74,7 @@ import numpy as np
 from jax.experimental.compilation_cache import compilation_cache
 
 from repro.core import protocol
+from repro.models.transformer import paged_decode_path
 # telemetry (DESIGN.md §15): one profiler span per step phase, priced
 # hops for the residual ledger, the trial flush
 from repro.obs import flush_trial as _obs_flush_trial
@@ -314,6 +316,13 @@ class ContinuousEngine:
         else:
             self.kv = SlotKVCache(model, cache_len, num_slots)
         self.kv.swap_buffers(self._on_device(self.kv.buffers))
+        #: how paged decode attention reads the pool ("paged_kernel" or
+        #: "gather", transformer.paged_decode_path); None without one
+        self.attn_path = None
+        if kv_layout == "paged" and "k" in self.kv.buffers:
+            k = self.kv.buffers["k"]
+            self.attn_path = paged_decode_path(
+                k.shape[3], k.shape[4], next(iter(k.devices())).platform)
         if prefix_cache:
             # radix-tree prefix cache (DESIGN.md §12): admission walks
             # the trie, leases every hit block at refcount+1, and starts
@@ -1196,9 +1205,23 @@ class ContinuousEngine:
         self._slot_out[slot] = out
         return None
 
+    def _decode_pages(self) -> Dict[str, object]:
+        """What decode attention reads, from the host's own lengths: the
+        pages of the decoding rows up to the token being decoded
+        (``kv_pages``) against the width of the whole table
+        (``table_pages``), and the path (``attn_path``)."""
+        if self.attn_path is None:
+            return {}
+        bs = self.kv.block_size
+        pages = sum((self.kv.length(s) + bs) // bs
+                    for s, r in enumerate(self._slot_req) if r is not None)
+        return {"kv_pages": pages,
+                "table_pages": self.kv.num_slots * self.kv.max_blocks_per_req,
+                "attn_path": self.attn_path}
+
     def _decode_micro_step(self, now: float,
                            phases: Dict[str, float]) -> List[ServeRequest]:
-        with span("serve.decode.dispatch", phases):
+        with span("serve.decode.dispatch", phases, **self._decode_pages()):
             state = self._decode_stream.ordered(self._state)
             if self.kv_layout == "paged":
                 nxt, buf, state = self._decode(self.params, self.kv.buffers,

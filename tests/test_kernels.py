@@ -1,29 +1,41 @@
-"""Pallas kernel validation: interpret=True execution vs pure-jnp oracles,
+"""Pallas kernel validation: interpreter execution vs pure-jnp oracles,
 swept over shapes and dtypes (per-kernel allclose requirement)."""
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.configs import get_config
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.msgq import ops as msgq_ops
 from repro.kernels.msgq.ops import copy_accounting
 from repro.kernels.msgq.ref import msgq_copy_ref
 from repro.kernels.paged_attention import ops as paged_ops
+from repro.kernels.paged_attention.paged_attention import pages_per_step
 from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.kernels.ssd_scan import ops as ssd_ops
 from repro.kernels.ssd_scan.ref import ssd_scan_ref
+from repro.models import transformer as T
 
 # the wrappers compile for the TPU by default; here every kernel body
-# runs in the Pallas interpreter on the CPU
+# runs in the Pallas interpreter on the CPU (paged attention in the TPU
+# interpreter, which models its DMAs and fills unwritten VMEM with NaN)
 flash_attention = functools.partial(flash_ops.flash_attention, interpret=True)
 msgq_copy = functools.partial(msgq_ops.msgq_copy, interpret=True)
-paged_attention = functools.partial(paged_ops.paged_attention, interpret=True)
 ssd_scan = functools.partial(ssd_ops.ssd_scan, interpret=True)
+
+
+def paged_attention(*args, **kwargs):
+    # the TPU interpreter runs JAX inside its callbacks: let no other
+    # computation be dispatched while one of its kernels is in flight
+    return jax.block_until_ready(paged_ops.paged_attention(
+        *args, interpret=pltpu.InterpretParams(), **kwargs))
 
 
 # ---------------------------------------------------------------------------
@@ -319,6 +331,101 @@ def test_paged_attention_mq_block_scatter_invariance():
     out2 = paged_attention(q, kp2, vp2, bt2, ln)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2),
                                atol=2e-6, rtol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# paged attention on the model's decode path
+# ---------------------------------------------------------------------------
+
+def _decode_pool(lengths, nb, bs=16, gs=8, hd=128, h=40, seed=0,
+                 lease_extra=1):
+    """Engine-like decode inputs: bfloat16 pool of (P, bs, Gs, hd) pages
+    with block 0 never leased, tables whose leases run ``lease_extra``
+    blocks past each length (reserved, not yet written), a parked row
+    (all -1, position -1) for each length 0, one query per row."""
+    rng = np.random.default_rng(seed)
+    need = [min(nb, -(-n // bs) + lease_extra) if n else 0 for n in lengths]
+    P = 1 + sum(need) + 3
+    perm = 1 + rng.permutation(P - 1)
+    tables = np.full((len(lengths), nb), -1, np.int32)
+    used = 0
+    for b, k in enumerate(need):
+        tables[b, :k] = perm[used:used + k]
+        used += k
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (len(lengths), 1, h, hd), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (P, bs, gs, hd), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (P, bs, gs, hd), jnp.bfloat16)
+    qpos = jnp.asarray([[n - 1] for n in lengths], jnp.int32)
+    return q, kp, vp, jnp.asarray(tables), qpos, qpos >= 0
+
+
+def _decode_cfg(softcap=0.0, swa=0):
+    return dataclasses.replace(get_config("qwen3-14b"),
+                               logit_softcap=softcap, swa_window=swa)
+
+
+BS, PPB = 16, 16                     # the pool's block, the kernel's step
+DECODE_LENGTHS = [1, BS, BS + 1, PPB * BS + 1, 0, 20 * BS]
+
+
+@pytest.mark.parametrize("softcap", [0.0, 30.0], ids=["plain", "softcap"])
+@pytest.mark.parametrize("is_global", [None, True, False],
+                         ids=["no_window", "global", "windowed"])
+def test_paged_decode_kernel_matches_gather(softcap, is_global):
+    """The model's two decode-attention branches agree at engine-like
+    shapes (40 query heads over 8 kv heads of 128, 16-token pages): the
+    kernel reading live pages in place, and the table-width gather. The
+    window is picked inside the jit from a traced per-layer flag, as the
+    hybrid family's scan picks it."""
+    assert pages_per_step(BS) == PPB
+    cfg = _decode_cfg(softcap, swa=0 if is_global is None else 40)
+    q, kp, vp, tables, qpos, wvalid = _decode_pool(DECODE_LENGTHS, nb=20)
+
+    def windowed(branch):
+        @jax.jit
+        def run(flag):
+            window = (None if is_global is None else
+                      jnp.where(flag, 0, cfg.swa_window).astype(jnp.int32))
+            return branch(window)
+        return jax.block_until_ready(run(bool(is_global)))
+
+    kern = windowed(lambda window: T._paged_kernel_context(
+        cfg, q, kp, vp, tables, qpos, wvalid, window,
+        interpret=pltpu.InterpretParams()))
+    gath = windowed(lambda window: T._paged_gather_context(
+        cfg, q, kp, vp, tables, qpos, window, jnp.bfloat16))
+    live = np.asarray(wvalid[:, 0])
+    assert kern.shape == gath.shape == q.shape
+    np.testing.assert_allclose(np.asarray(kern, np.float32)[live],
+                               np.asarray(gath, np.float32)[live],
+                               atol=2e-2, rtol=2e-2)
+    np.testing.assert_array_equal(np.asarray(kern, np.float32)[~live], 0.0)
+
+
+def test_paged_decode_kernel_leaves_unread_pages_unread():
+    """Blocks past each row's length (leased, not written), unleased
+    blocks and block 0 (where the gather clamps -1 entries) all hold NaN:
+    the kernel's output is finite and equals its output with them
+    zeroed, so it reads no page past a row's length. The gather path
+    reads them all and cannot pass this."""
+    q, kp, vp, tables, qpos, wvalid = _decode_pool(
+        DECODE_LENGTHS, nb=20, lease_extra=2)
+    lengths = jnp.where(wvalid[:, 0], qpos[:, 0] + 1, 0)
+    read = np.zeros(kp.shape[0], bool)
+    for row, n in zip(np.asarray(tables), np.asarray(lengths)):
+        read[row[:-(-int(n) // BS)]] = True
+    assert not read[0] and (~read[1:]).sum() > 0
+    unread = jnp.asarray(np.flatnonzero(~read))
+
+    def run(fill):
+        return np.asarray(paged_attention(
+            q[:, 0], kp.at[unread].set(fill), vp.at[unread].set(fill),
+            tables, lengths), np.float32)
+
+    poisoned = run(jnp.nan)
+    assert np.isfinite(poisoned).all()
+    np.testing.assert_array_equal(poisoned, run(0.0))
 
 
 # ---------------------------------------------------------------------------

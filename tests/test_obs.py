@@ -267,6 +267,51 @@ def test_engine_phases_in_profile(bundle, tmp_path):
                and a["waited_s"] >= 0 for a in admits)
 
 
+def test_decode_dispatch_counts_pages(bundle, tmp_path):
+    """``serve.decode.dispatch`` carries what decode attention reads,
+    from the host's lengths: each decoding row's pages up to the token it
+    decodes, against the whole tables' width, and the path (the gather
+    on a CPU)."""
+    from repro.serve import ServeRequest
+    cfg = bundle[0]
+    eng = _paged_engine(bundle)
+    assert eng.attn_path == "gather"
+    eng.generate({"tokens": _prompt(cfg, 20)}, 3)           # compile
+    eng.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        for rid, n in enumerate((20, 9)):
+            eng.submit(ServeRequest(rid=rid, batch={"tokens": _prompt(cfg, n)},
+                                    max_new_tokens=4), 0.0)
+        while not eng.idle:
+            eng.step(0.0)
+    spans = [s[3] for s in _host_spans(tmp_path, ("serve.decode.dispatch",))]
+    assert spans
+    table = 2 * 6                          # 2 rows of 48 // 8 entries
+    assert all(a["table_pages"] == table and a["attn_path"] == "gather"
+               for a in spans)
+    # the 20-token prompt decodes at positions 20..22 (3 pages of 8), the
+    # 9-token one at 9..11 (2 pages); alone or together
+    assert {a["kv_pages"] for a in spans} <= {2, 3, 5}
+    assert 5 in {a["kv_pages"] for a in spans}
+
+
+def test_decode_pages_follow_lengths(bundle):
+    eng = _paged_engine(bundle)
+    from repro.serve import ServeRequest
+    eng.submit(ServeRequest(rid=0, batch={"tokens": _prompt(bundle[0], 14)},
+                            max_new_tokens=6), 0.0)
+    seen = []
+    while not eng.idle:
+        pages = eng._decode_pages()
+        if eng.num_decoding:
+            slot = eng.kv.live_slots[0]
+            seen.append((eng.kv.length(slot), pages["kv_pages"]))
+        eng.step(0.0)
+    # a row decoding the token at position n reads ceil((n + 1) / 8) pages
+    assert seen and all(p == -(-(n + 1) // 8) for n, p in seen)
+    assert {p for _, p in seen} == {2, 3}
+
+
 def test_last_step_phases_without_profiler(bundle):
     cfg = bundle[0]
     eng = _paged_engine(bundle)

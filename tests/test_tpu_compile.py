@@ -14,6 +14,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels.flash_attention.flash_attention import flash_attention_fwd
@@ -118,3 +119,47 @@ def test_gemma_2b_paged_decode_step_fits_one_chip(one_chip):
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert mem.argument_size_in_bytes > 4.5e9        # 2.5 B bf16 params
     assert used < V5E_HBM_BYTES, used
+
+
+def test_qwen3_paged_decode_reads_pages_in_place(one_chip):
+    """The paged decode program of a 2-layer cut of qwen3-14b at the
+    longdoc cell's serving shapes (16 rows of 800 table entries, an
+    85 120-token pool of 16-token blocks) holds the paged-attention
+    kernel and gathers no copy of K/V as wide as the tables
+    (16 x 800 x 16 token slots a layer)."""
+    import dataclasses
+    import re
+
+    from repro.config import ServeConfig, TrainConfig
+    from repro.configs import get_config
+    from repro.models.registry import build_model
+    from repro.serve import ContinuousEngine
+    cfg = dataclasses.replace(get_config("qwen3-14b"), num_layers=2)
+    model = build_model(cfg, TrainConfig(param_dtype="bfloat16",
+                                         compute_dtype="bfloat16",
+                                         remat=False), ServeConfig(), tp=1)
+    rows, block_size, per_row, pool_tokens = 16, 16, 800, 85120
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = on_chip(jax.eval_shape(functools.partial(
+        model.init_paged_cache, pool_tokens // block_size, block_size,
+        num_rows=rows)))
+    state = {"tok": spec((rows, 1, 1), I32), "pos": spec((rows,), I32),
+             "keys": spec((rows, 2), jnp.uint32), "temp": spec((rows,), F32)}
+    text = jax.jit(ContinuousEngine._decode_impl_paged(model),
+                   donate_argnums=(1, 2)).lower(
+        params, pool, state, spec((rows, per_row), I32)).compile().as_text()
+    assert "tpu_custom_call" in text
+    gathered = []
+    for dims in re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} gather\(", text):
+        shape = [int(d) for d in dims.split(",")]
+        gathered.append(int(np.prod(shape[:-2])) if len(shape) > 2 else 1)
+    assert gathered and max(gathered) < rows * per_row * block_size, gathered

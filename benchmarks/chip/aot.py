@@ -39,7 +39,7 @@ def rehearse(workload: str, device) -> dict:
     bs = int(cell.config["block_size"])
     S, P, C = int(s["rows"]), int(s["prefill_rows"]), int(s["prefill_chunk"])
     mbr = -(-int(s["cache_len"]) // bs)
-    model = drive.program_model(cell.config)
+    model = drive.program_model(cell.arch.program_config(cell.config))
     on = SingleDeviceSharding(device)
 
     def place(tree):

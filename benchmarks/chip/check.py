@@ -2,7 +2,8 @@
 
 After the window, a sample of the requests it finished, drawn from the
 seed and always holding the one with the most served tokens, is run
-through the plain reference (``reference.py``) over its prompt and its
+through the plain reference (the ``logits`` of the configuration's
+architecture module, ``models/<model_type>.py``) over its prompt and its
 served tokens (teacher-forced). Each request of the sample gives its last
 ``check.per_request`` served tokens (the deepest context it decoded at),
 so that the sample's ``check.served_tokens`` come from several requests,
@@ -25,8 +26,6 @@ from typing import List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from benchmarks.chip import reference
 
 
 @jax.jit
@@ -67,32 +66,34 @@ def checked_tokens(picked: Sequence, rule: dict) -> int:
     return sum(min(len(r.served), int(rule["per_request"])) for r in picked)
 
 
-def gaps(config: dict, weights, prompt: np.ndarray, served: np.ndarray,
-         last: int, quant: str | None = None) -> np.ndarray:
+def gaps(reference, config: dict, weights, prompt: np.ndarray,
+         served: np.ndarray, last: int, quant: str | None = None
+         ) -> np.ndarray:
     """For each of the ``last`` final served tokens: the gap of the served
     token (``quant`` None), or of the token the ``quant`` reference puts
-    first (the control)."""
+    first (the control). ``reference`` is the architecture's
+    ``logits``."""
     seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     n = min(last, len(served))
     first = len(seq) - n
-    ref = reference.logits(config, weights, seq, first)
+    ref = reference(config, weights, seq, first)
     tok = np.zeros((ref.shape[0],), np.int32)
     tok[:n] = served[len(served) - n:]
     if quant is None:
         out = _gaps(ref, jnp.asarray(tok))
     else:
-        ctl = reference.logits(config, weights, seq, first, quant=quant)
+        ctl = reference(config, weights, seq, first, quant=quant)
         out = _control_gaps(ref, ctl)
         del ctl
     del ref
     return np.asarray(out)[:n]
 
 
-def gap_stats(config: dict, weights, picked: Sequence, rule: dict,
-              quant: str | None = None) -> dict:
+def gap_stats(reference, config: dict, weights, picked: Sequence,
+              rule: dict, quant: str | None = None) -> dict:
     """The widest and the mean gap over every checked token of
     ``picked``."""
-    g = np.concatenate([gaps(config, weights, r.prompt, r.served,
+    g = np.concatenate([gaps(reference, config, weights, r.prompt, r.served,
                              int(rule["per_request"]), quant)
                         for r in picked])
     return {"widest": float(g.max()), "mean": float(g.mean())}

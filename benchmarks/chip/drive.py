@@ -1,6 +1,7 @@
 """One cell through the program's paged ``ContinuousEngine``.
 
-Builds the model from the configuration file, draws its weights, warms
+Builds the model from the configuration file (through its
+architecture's ``program_config``), draws its weights, warms
 up the engine's own programs, then runs the traffic through
 ``submit``/``step`` on one host thread: warm-in, then the measured
 window. The loop is the serve driver's ``_drive_wall_clock`` adapted to a
@@ -11,7 +12,6 @@ queue runs short), and every request's progress is read after each step.
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import gc
 import time
 from dataclasses import dataclass, field
@@ -22,31 +22,13 @@ import numpy as np
 
 from benchmarks.chip import e2e, traffic, weights as weights_mod
 
-#: program config field for each published key of a configuration file
-_FIELDS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
-           "num_attention_heads": "num_heads",
-           "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-           "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-           "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
-           "num_experts": "num_experts", "num_experts_per_tok": "top_k",
-           "tie_word_embeddings": "tie_embeddings"}
 
-
-def program_model(config: dict):
-    """The program's model for a configuration file, in bfloat16."""
+def program_model(cfg):
+    """The program's model for ``cfg`` (the ``ModelConfig`` an
+    architecture's ``program_config`` gives), in bfloat16."""
     from repro.config import ServeConfig, TrainConfig
-    from repro.configs import get_config
     from repro.models.registry import build_model
 
-    c = dict(config["config"])
-    if c.get("num_experts") and not c.get("norm_topk_prob", True):
-        raise ValueError("the program always renormalises the top-k gates; "
-                         "a configuration with norm_topk_prob false cannot "
-                         "run as stated")
-    c.setdefault("head_dim", c["hidden_size"] // c["num_attention_heads"])
-    base = get_config(config["arch"])
-    cfg = dataclasses.replace(base, **{
-        f: type(getattr(base, f))(c[k]) for k, f in _FIELDS.items() if k in c})
     tcfg = TrainConfig(param_dtype="bfloat16", compute_dtype="bfloat16",
                        remat=False)
     return build_model(cfg, tcfg, ServeConfig(), tp=1)
@@ -291,6 +273,8 @@ class Driver:
             rec.gen_at_end = rec.generated
 
 
-def draw_weights(model, seed: int):
+def draw_weights(model, seed: int, arch):
+    """``model``'s weights from ``seed``, each leaf at the scale the
+    architecture's module gives it, or at ``weights.py``'s."""
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    return weights_mod.draw(shapes, seed)
+    return weights_mod.draw(shapes, seed, getattr(arch, "draw_scale", None))

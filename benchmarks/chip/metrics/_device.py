@@ -1,6 +1,7 @@
 """Shared arithmetic of the device-trace readers: the work the harness
-saw dispatched in the traced span, against the device time of the
-program's executions there."""
+saw dispatched in the traced span, counted by the architecture's module
+(``run.counts``), against the device time of the program's executions
+there."""
 
 from benchmarks.chip import work as W
 
@@ -10,9 +11,9 @@ def dispatches(run, program):
     out = []
     for step in run.work:
         if program == "decode" and step.decode_ctx:
-            out.append(W.decode_work(run.dims, step.decode_ctx))
+            out.append(run.counts.decode_work(step.decode_ctx))
         elif program == "chunk" and step.chunk_rows:
-            out.append(W.chunk_work(run.dims, step.chunk_rows))
+            out.append(run.counts.chunk_work(step.chunk_rows))
     return out
 
 

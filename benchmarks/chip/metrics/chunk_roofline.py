@@ -1,6 +1,6 @@
 """Kernels (the jnp ops of the chunk program): the least time the chip
-could take for the chunk work the traffic needs (work.py, peaks.json) over
-the measured device time per execution (%)."""
+could take for the chunk work the traffic needs (the architecture's
+counts, peaks.json) over the measured device time per execution (%)."""
 
 from benchmarks.chip.metrics._device import roofline
 

@@ -1,6 +1,7 @@
 """Whole step: model FLOPs that the traffic needed in the traced span
-(work.py: routed experts only, causal attention) over the span's length
-times the chip's peak (%). Idle time counts against it."""
+(the architecture's counts, by work.py's rules: routed experts only,
+causal attention) over the span's length times the chip's peak (%).
+Idle time counts against it."""
 
 from benchmarks.chip.metrics._device import dispatches
 

@@ -13,8 +13,11 @@ against the plain reference; print the result as the last line of
 standard output, after the compared numbers on standard error.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` traces
-the last 4 s of the window with ``jax.profiler`` and
-reports its per-layer metrics instead.
+the last 4 s of the window with ``jax.profiler`` and reports its
+per-layer metrics instead, read from the profile reduced by the device's
+programs, the program's own spans and the model's named scopes
+(``trace_scopes.py``; after the window, the engine's two programs are
+compiled once more for their HLO text, which names each op's scope).
 """
 
 from __future__ import annotations
@@ -54,12 +57,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     the widest and the mean gap of the program and of the control."""
     import jax
 
-    from benchmarks.chip import check, drive, e2e, trace_reduce, work
+    from benchmarks.chip import (check, drive, e2e, trace_reduce,
+                                 trace_scopes, work)
 
-    settings, config = cell.settings, cell.config
+    settings, config, arch = cell.settings, cell.config, cell.arch
     vocab = int(config["config"]["vocab_size"])
-    model = drive.program_model(config)
-    params = drive.draw_weights(model, seed)
+    model = drive.program_model(arch.program_config(config))
+    params = drive.draw_weights(model, seed, arch)
     eng = drive.make_engine(model, params, config, settings["serving"])
     drive.warm_up(eng, vocab)
     # everything set-up made lives to the end: keep it out of the
@@ -102,13 +106,16 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": jax.device_count(),
               "memory_peak_bytes": stats.get("peak_bytes_in_use")}
+    host = drv.host_stats(t0, t1)
     if trace:
-        reduced = trace_reduce.reduce(
-            trace_reduce.events(trace_reduce.find_xplane(str(TRACE_DIR))),
+        t_reduce = time.perf_counter()
+        reduced = trace_scopes.reduce(
+            trace_scopes.events(trace_reduce.find_xplane(str(TRACE_DIR)),
+                                eng.program_text()),
             settings["programs"])
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        host["trace_reduce_s"] = time.perf_counter() - t_reduce
 
-    host = drv.host_stats(t0, t1)
     host["gc_pauses"], host["gc_longest_ms"] = len(pauses.seconds), 1e3 * max(
         pauses.seconds, default=0.0)
     window = e2e.window_metrics(drv.records, t0, t1)
@@ -123,7 +130,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     else:
         view = SimpleNamespace(
             records=drv.records, steps=drv.steps, work=drv.work,
-            t0=t0, t1=t1, trace=reduced, dims=work.Dims.of(config),
+            t0=t0, t1=t1, trace=reduced, counts=arch.counts(config),
             peaks=work.load_peaks(dev.device_kind) if dev.platform == "tpu"
             else None, peak_blocks=drv.peak_blocks,
             pool_blocks=eng.kv.pool.num_blocks)
@@ -150,11 +157,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     rule = settings["check"]
     picked = check.sample(finished, seed, rule)
     t_ref = time.perf_counter()
-    stats = check.gap_stats(config, params, picked, rule) if picked else None
+    stats = (check.gap_stats(arch.logits, config, params, picked, rule)
+             if picked else None)
     t_ref = time.perf_counter() - t_ref
     judged = stats
     if control and picked:
-        judged = check.gap_stats(config, params, picked, rule, quant=control)
+        judged = check.gap_stats(arch.logits, config, params, picked, rule,
+                                 quant=control)
     limit = float(rule["limit"])
     gap = judged[rule["statistic"]] if judged else None
     result = {"correct": gap is not None and gap <= limit,

@@ -247,8 +247,9 @@ def traced_window(cell, seed: int, seconds: float,
     trace_dir = str(trace_dir or run.TRACE_DIR)
     settings, config = cell.settings, cell.config
     vocab = int(config["config"]["vocab_size"])
-    model = drive.program_model(config)
-    params = drive.draw_weights(model, seed)
+    arch = cell.arch
+    model = drive.program_model(arch.program_config(config))
+    params = drive.draw_weights(model, seed, arch)
     eng = drive.make_engine(model, params, config, settings["serving"])
     drive.warm_up(eng, vocab)
     # the profiler's first start costs seconds on a TPU: pay it here
@@ -275,7 +276,7 @@ def traced_window(cell, seed: int, seconds: float,
     dev = jax.devices()[0]
     view = SimpleNamespace(
         records=drv.records, steps=drv.steps, work=drv.work, t0=t0, t1=t1,
-        trace=reduced, dims=work.Dims.of(config),
+        trace=reduced, counts=arch.counts(config),
         peaks=(work.load_peaks(dev.device_kind) if dev.platform == "tpu"
                else None),
         peak_blocks=drv.peak_blocks, pool_blocks=eng.kv.pool.num_blocks)

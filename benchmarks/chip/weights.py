@@ -8,7 +8,8 @@ slice: peak memory at set-up stays near the weights themselves.
 Values are uniform with the initialiser's standard deviation (fan-in
 ``1/sqrt(in)`` for projections, 0.02 for the embedding); norm weights
 are ``1 +/- 0.1`` so that a norm applied twice, or not at all, shows in
-the logits.
+the logits. An architecture's module may give the scale of its own
+leaves (``draw_scale``); the rule here is the default.
 """
 
 from __future__ import annotations
@@ -42,8 +43,12 @@ def _fan_in(path: str, shape) -> int:
     return shape[-2]
 
 
-def _scale(path: str, shape) -> tuple[float, float]:
-    """(centre, half-width) of the uniform a leaf is drawn from."""
+def _scale(path: str, shape, hook=None) -> tuple[float, float]:
+    """(centre, half-width) of the uniform a leaf is drawn from: what
+    ``hook(path, shape)`` gives, where it gives one."""
+    own = hook(path, shape) if hook else None
+    if own is not None:
+        return own
     name = path.rsplit("/", 1)[-1]
     if name in ("w", "q_norm", "k_norm"):
         return 1.0, 0.1
@@ -75,17 +80,18 @@ def _paths(tree):
     return names, [leaf for _, leaf in flat], treedef
 
 
-def draw(shapes, seed: int):
+def draw(shapes, seed: int, scale=None):
     """Weights for the tree of ``jax.ShapeDtypeStruct`` ``shapes`` (as
     ``jax.eval_shape(model.init, key)`` gives them), made on the default
-    device from ``seed`` in one jitted call."""
+    device from ``seed`` in one jitted call; ``scale`` is the
+    architecture's ``draw_scale`` hook, if it has one."""
     names, leaves, treedef = _paths(shapes)
 
     def make(key):
         keys = jax.random.split(key, len(leaves))
         out = []
         for k, name, leaf in zip(keys, names, leaves):
-            centre, half = _scale(name, leaf.shape)
+            centre, half = _scale(name, leaf.shape, scale)
             out.append(_draw_leaf(k, tuple(leaf.shape), leaf.dtype,
                                   centre, half))
         return jax.tree_util.tree_unflatten(treedef, out)

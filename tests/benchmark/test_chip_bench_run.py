@@ -1,6 +1,7 @@
 """The chip benchmark's harness end to end, on the CPU at tiny sizes: the
-loader, the refusals, the compile guard, a run, and a run with a token
-altered where the engine produces it, which must come out not correct."""
+loader, an architecture added as files, the refusals, the compile guard,
+a run, and runs with tokens altered where the engine produces them,
+which must come out not correct."""
 
 import json
 import os
@@ -11,7 +12,8 @@ import jax.numpy as jnp
 import pytest
 
 from benchmarks.chip import drive, run, spec
-from chip_bench_helpers import REPO, TINY_CELLS, add_tiny_cells, copy_benchmark
+from chip_bench_helpers import (QWEN2_CELL, REPO, TINY_CELLS, add_architecture,
+                                add_tiny_cells, copy_benchmark)
 
 RUN = ["python3", "benchmarks/chip/run.py"]
 
@@ -40,11 +42,39 @@ def test_every_workload_resolves_its_files():
             assert m["moves"] in {e["name"] for e in cell.end_to_end}
 
 
-def test_added_files_are_found_without_editing(tmp_path):
+def _alter_last_token(monkeypatch):
+    """Every request the engine finishes hands back its last served token
+    shifted by one: one altered token in each, where it is produced."""
+    make = drive.make_engine
+
+    def broken(*args, **kwargs):
+        eng = make(*args, **kwargs)
+        step = eng.step
+
+        def altered(*a):
+            done = step(*a)
+            for req in done:
+                req.output[req.generated - 1] = (
+                    req.output[req.generated - 1] + 1) % 256
+            return done
+
+        eng.step = altered
+        return eng
+
+    monkeypatch.setattr(drive, "make_engine", broken)
+
+
+def test_added_files_are_found_without_editing(tmp_path, monkeypatch):
+    """Cells, a metric and an architecture the harness has no module for
+    (Qwen2: QKV biases, no QK-norm) join a copy of the benchmark by new
+    files and new ``BENCHMARK.json`` entries alone; the new architecture's
+    tiny cell comes out correct, and not with a served token altered."""
     root = copy_benchmark(tmp_path)
-    before = {p: p.read_bytes() for p in (root / "benchmarks").rglob("*")
-              if p.is_file()}
+    old_bench = json.loads((root / "BENCHMARK.json").read_text())
+    before = {p: p.read_bytes() for p in root.rglob("*")
+              if p.is_file() and p.name != "BENCHMARK.json"}
     add_tiny_cells(root)
+    add_architecture(root)
     metric = root / "benchmarks" / "chip" / "metrics" / "tiny_count.py"
     metric.write_text("def read(run):\n    return 7.0\n")
     bench = json.loads((root / "BENCHMARK.json").read_text())
@@ -55,12 +85,34 @@ def test_added_files_are_found_without_editing(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     for p, data in before.items():
         assert p.read_bytes() == data, f"{p} was edited"
+    for key, entries in old_bench.items():
+        if not (isinstance(entries, list) and isinstance(entries[0], dict)):
+            assert bench[key] == entries, key
+            continue
+        for old in entries:
+            new = next(e for e in bench[key] if e["name"] == old["name"])
+            assert {k: v for k, v in new.items() if k != "workloads"} == \
+                {k: v for k, v in old.items() if k != "workloads"}
+            assert set(old.get("workloads", [])) <= set(
+                new.get("workloads", []))
     for name, (config, mix, _, _) in TINY_CELLS.items():
         cell = spec.cell(name, root)
         assert cell.config["name"] == config and cell.mix["name"] == mix
     assert spec.cell("tiny-dense-batch", root).readers["tiny_count.x"](None) == 7.0
     for w in spec.benchmark()["workloads"]:
         assert spec.cell(w["name"], root).name == w["name"]
+
+    cell = spec.cell(QWEN2_CELL, root)
+    assert cell.arch.__name__.endswith("qwen2")
+    r = run.run_cell(cell, 2 ** 31 + 21, 1.5, False, t_process=0.0)
+    gap = r["checks"]["mean_logit_gap"]
+    assert r["correct"] and gap["value"] <= gap["limit"]
+    assert r["checked"]["tokens"] >= 24
+    _alter_last_token(monkeypatch)
+    r = run.run_cell(spec.cell(QWEN2_CELL, root), 2 ** 31 + 21, 1.5, False,
+                     t_process=0.0)
+    gap = r["checks"]["mean_logit_gap"]
+    assert not r["correct"] and gap["value"] > gap["limit"]
 
 
 def test_refuses_a_host_without_a_tpu():

@@ -1,12 +1,13 @@
-"""work.py against hand counts for both configurations, and the peaks
-table keyed by device kind."""
+"""The architecture modules' work counts against hand counts for both
+configurations, work.py's shared arithmetic, and the peaks table keyed by
+device kind."""
 
 import json
 from pathlib import Path
 
 import pytest
 
-from benchmarks.chip import work
+from benchmarks.chip import spec, work
 
 REPO = Path(__file__).resolve().parents[2]
 
@@ -15,14 +16,18 @@ CONFIGS = REPO / "benchmarks" / "chip" / "configs"
 
 #: OLMoE-1B-7B-0924's published widths (hf allenai/OLMoE-1B-7B-0924
 #: config.json) at 8 of its 16 layers
-OLMOE = {"config": {
+OLMOE = {"model_type": "olmoe", "config": {
     "hidden_size": 2048, "intermediate_size": 1024, "num_hidden_layers": 8,
     "num_attention_heads": 16, "num_key_value_heads": 16, "num_experts": 64,
     "num_experts_per_tok": 8, "vocab_size": 50304}}
 
 
+def _counts(config):
+    return spec.architecture(config["model_type"]).counts(config)
+
+
 def _dims(name):
-    return work.Dims.of(json.loads((CONFIGS / f"{name}.json").read_text()))
+    return _counts(json.loads((CONFIGS / f"{name}.json").read_text()))
 
 
 def test_qwen3_hand_counts():
@@ -34,7 +39,7 @@ def test_qwen3_hand_counts():
     assert d.kv_bytes_per_token == 8 * 128 * 2 * 2 * 10 == 40_960
     head = 5120 * 151936 * 2
     weights = 10 * (per_layer * 2 + 4 * 5120 * 2) + head + 16 * 5120 * 2
-    flops, nbytes = work.decode_work(d, [1000] * 16)
+    flops, nbytes = d.decode_work([1000] * 16)
     assert nbytes == pytest.approx(weights + 16 * 1000 * 40_960)
     assert flops == pytest.approx(
         16 * (2 * per_layer * 10 + 2 * 5120 * 151936
@@ -42,7 +47,7 @@ def test_qwen3_hand_counts():
 
 
 def test_olmoe_hand_counts():
-    d = work.Dims.of(OLMOE)
+    d = _counts(OLMOE)
     attn = 4 * 2048 * 2048
     routed = 8 * 3 * 2048 * 1024          # top-8 of 64 experts
     router = 2048 * 64
@@ -61,11 +66,20 @@ def test_olmoe_hand_counts():
 
 def test_chunk_attention_is_causal():
     d = _dims("qwen3-14b-pp4")
-    flops, nbytes = work.chunk_work(d, [(100, 4)])
+    flops, nbytes = d.chunk_work([(100, 4)])
     seen = 101 + 102 + 103 + 104
     assert flops == pytest.approx(4 * d.body_flops_per_token + d.head_flops
                                   + d.attn_flops(1) * seen)
     assert nbytes == pytest.approx(d.weight_bytes(4) + 104 * 40_960)
+
+
+@pytest.mark.parametrize("pos0, n, window, want", [
+    (100, 4, None, 101 + 102 + 103 + 104),
+    (0, 6, 4, 1 + 2 + 3 + 4 + 4 + 4),        # the window fills at key 4
+    (10, 3, 4, 3 * 4),                       # past it: window keys each
+    (1, 2, 16, 2 + 3)])                      # inside it: causal
+def test_keys_seen(pos0, n, window, want):
+    assert work.keys_seen(pos0, n, window) == want
 
 
 def test_least_time_names_its_bound():
